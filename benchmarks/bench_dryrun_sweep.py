@@ -34,7 +34,9 @@ def run():
     cells = SMOKE_CELLS if smoke else CELLS
     rows = []
     for arch, shape in cells:
-        env = dict(os.environ, REPRO_DEVICES="16")
+        # the child compiles on forced host devices; it must never reach
+        # for the chip this process may hold
+        env = dict(os.environ, REPRO_DEVICES="16", JAX_PLATFORMS="cpu")
         env.setdefault("PYTHONPATH", "src")
         proc = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun",

@@ -58,6 +58,9 @@ def main(argv=None) -> None:
                 f"unknown --only lane(s) {', '.join(bad)}; "
                 f"valid: {', '.join(tag for tag, _ in MODULES)}"
             )
+    from repro.common import compile_cache
+
+    compile_cache.enable()
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
     if args.out:

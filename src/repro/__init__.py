@@ -1,11 +1,2 @@
 """Reproduction of "A Quantitative Approach for Adopting Disaggregated
-Memory in HPC Systems".
-
-Importing the package eagerly loads :mod:`repro.common.parallel`, whose
-import installs the jax version-compat shims (``jax.sharding.AxisType`` and
-the ``axis_types=`` kwarg of ``jax.make_mesh``) so every entry point —
-including bare subprocess snippets that only import one leaf module — sees
-a uniform jax surface.
-"""
-
-from repro.common import parallel as _parallel  # noqa: F401  (compat shims)
+Memory in HPC Systems"."""

@@ -53,6 +53,25 @@ V5E = ChipSpec(
     mxu_dim=128,
 )
 
+# JAX's `device_kind` -> the chip it names. A device that is not listed
+# is an error, never a silent v5e default: every constant above would be
+# wrong for it.
+CHIPS = {
+    "TPU v5 lite": V5E,
+    "TPU v5e": V5E,
+}
+
+
+def chip_for(device_kind: str) -> ChipSpec:
+    """The described chip for a JAX `device_kind`; raises if unknown."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"device_kind {device_kind!r} is not described in common/hw.py "
+            f"(known: {sorted(CHIPS)})") from None
+
+
 # v5e hosts carry 8 chips (4x2) with PCIe gen3 x16 per 2 chips in practice;
 # we model a per-chip effective 16 GB/s and a shared 64 GB/s domain, which is
 # deliberately *slower relative to HBM* than the paper's UPI (34 vs 73 GB/s):

@@ -8,74 +8,10 @@ else relies on pjit auto-sharding + constraints.
 from __future__ import annotations
 
 import dataclasses
-import enum
-import inspect
 from typing import Optional
 
 import jax
 from jax.sharding import PartitionSpec as P
-
-
-# ------------------------------------------------------------- jax compat
-def _install_jax_compat() -> None:
-    """Let call sites use the modern mesh spelling on older jax.
-
-    jax >= 0.5 exposes `jax.sharding.AxisType` and `jax.make_mesh(...,
-    axis_types=...)`; 0.4.x has neither (the internal enum is
-    `jax._src.mesh.AxisTypes` and `Auto` is the implicit default). The repo
-    standardizes on the modern spelling, so on old runtimes we publish an
-    `AxisType` alias and wrap `make_mesh` to swallow the kwarg.
-    """
-    if not hasattr(jax.sharding, "AxisType"):
-        try:
-            from jax._src.mesh import AxisTypes as _axis_type
-        except ImportError:
-            class _axis_type(enum.Enum):
-                Auto = enum.auto()
-                Explicit = enum.auto()
-                Manual = enum.auto()
-        jax.sharding.AxisType = _axis_type
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-        def _shard_map(f, mesh=None, in_specs=None, out_specs=None, *,
-                       check_vma=None, **kwargs):
-            if check_vma is not None and "check_rep" not in kwargs:
-                kwargs["check_rep"] = check_vma  # renamed in jax >= 0.6
-            return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, **kwargs)
-
-        jax.shard_map = _shard_map
-
-    if not hasattr(jax, "make_mesh"):
-        # pre-0.4.35 jax has no make_mesh at all
-        def _make_mesh_from_scratch(axis_shapes, axis_names, *,
-                                    devices=None, axis_types=None):
-            del axis_types
-            import numpy as _np
-            devs = list(devices) if devices is not None else jax.devices()
-            n = int(_np.prod(axis_shapes))
-            grid = _np.array(devs[:n], dtype=object).reshape(axis_shapes)
-            return jax.sharding.Mesh(grid, axis_names)
-
-        jax.make_mesh = _make_mesh_from_scratch
-    # signature() is checked on the current jax.make_mesh, so a re-import of
-    # this module never double-wraps.
-    elif "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _orig_make_mesh = jax.make_mesh
-
-        def _make_mesh(axis_shapes, axis_names, *args, axis_types=None,
-                       **kwargs):
-            del axis_types  # Auto is the only behavior old jax offers
-            return _orig_make_mesh(axis_shapes, axis_names, *args, **kwargs)
-
-        _make_mesh.__name__ = "make_mesh"
-        _make_mesh.__doc__ = _orig_make_mesh.__doc__
-        jax.make_mesh = _make_mesh
-
-
-_install_jax_compat()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,3 +53,32 @@ class ParallelCtx:
 
 
 SINGLE = ParallelCtx()  # no mesh: pure single-device semantics
+
+
+def per_kv_shard(ctx: Optional[ParallelCtx], n_kv: int, fn, split, whole):
+    """Call `fn(*split, *whole)` — a Pallas kernel op — once per device.
+
+    A Mosaic custom call cannot be partitioned by GSPMD, so under a mesh
+    the paged kernels run inside `shard_map`, as `sharded_mha` does for
+    the dense flash kernel. `split` operands and every output carry their
+    heads on the second-minor axis (q (B, H, D), chunks and pool pages
+    (..., KV, D), (scale, zero) rows (..., KV, 2)); they are split over
+    the tp axis when it divides the `n_kv` KV heads (whole GQA groups per
+    shard — the pool's own split, `runtime.sharding.paged_cache_pspec`),
+    else every device runs the whole kernel. `whole` operands (block
+    tables, lengths, page ids) are replicated. Meshless: a plain call."""
+    if ctx is None or ctx.mesh is None:
+        return fn(*split, *whole)
+    tp = ctx.axis_size(ctx.tp_axis)
+    ax = ctx.tp_axis if tp > 1 and n_kv % tp == 0 else None
+
+    def heads(x):
+        return P(*([None] * (len(x.shape) - 2)), ax, None)
+
+    out = jax.eval_shape(fn, *split, *whole)
+    return jax.shard_map(
+        fn, mesh=ctx.mesh,
+        in_specs=tuple(heads(x) for x in split) + (P(),) * len(whole),
+        out_specs=jax.tree.map(heads, out),
+        check_vma=False,
+    )(*split, *whole)

@@ -1,5 +1,5 @@
 """smollm-360m — 32L d=960 15H (GQA kv=5) d_ff=2560 vocab=49152 — llama-arch
-small.  [hf:HuggingFaceTB/SmolLM-135M; hf]
+small.  [hf:HuggingFaceTB/SmolLM-360M; hf]
 
 Control arch for the tiering technique: the whole training state fits HBM,
 so a correct placement policy must choose all-HBM (pool fraction -> 0).
@@ -18,7 +18,7 @@ CONFIG = ModelConfig(
     d_ff=2560,
     vocab_size=49152,
     tie_embeddings=True,
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )
 
 

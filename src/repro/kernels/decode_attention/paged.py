@@ -12,17 +12,26 @@ previous page's flash update is still computing — the same
 fetch-one-page-ahead overlap the prefetch subsystem models at the tier
 level, here done by Mosaic's double-buffered pipeline at the VMEM level.
 
-Block-quantized pools (`repro.kernels.quant`): with int8 page payloads the
-per-page float32 (scale, zero) arrays ride the SAME scalar-prefetch
-channel next to the block table, and the kernel applies the dequant
-epilogue `q * scale + zero` right after each page's gather — the fp
-values never exist in HBM, only in the VMEM tile the flash update is
-about to consume, so the pool-link bytes are the int8 payload plus the
-per-page scalars and nothing else.
+Tiling: a pool page is (page, KV, hd), so its (KV, hd) minor dims must be
+fetched whole (Mosaic's block rule: the last two block dims equal the
+array's or are (8, 128)-aligned). One grid step therefore DMAs one
+physical page with ALL its KV heads and walks the heads in a static loop;
+each KV head's `rep` GQA query heads attend to it as one (rep, hd) x
+(hd, page) contraction. Queries arrive grouped as (B, KV, rep, hd) so
+every block is whole in its minor dims too.
 
-Grid (B, H, n_logical_pages); the page dimension is sequential
-("arbitrary") so the online-softmax accumulators live in VMEM scratch
-across iterations, exactly like the dense `decode_attention.py` kernel.
+Block-quantized pools (`repro.kernels.quant`): with int8 page payloads the
+per-page float32 (scale, zero) pairs travel as a (KV, 2) SMEM block chased
+through the same block-table entry as the payload (per-token sub-scales as
+a (page, KV, 2) VMEM block), and the kernel applies the dequant epilogue
+`q * scale + zero` right after each page's gather — the fp values never
+exist in HBM, only in the VMEM tile the flash update is about to consume.
+(Scalar prefetch would put the whole (P_phys, KV, 2) array in SMEM, which
+a full-size pool overflows.)
+
+Grid (B, n_logical_pages); the page dimension is sequential ("arbitrary")
+so the online-softmax accumulators live in VMEM scratch across
+iterations, exactly like the dense `decode_attention.py` kernel.
 Out-of-length positions are masked by an iota test against `lengths`;
 block-table entries past a sequence's last valid page must still name a
 real physical page (the public wrapper in ops.py clamps them to 0) so the
@@ -40,31 +49,47 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
-def _flash_update(s, v, acc, m_sc, l_sc):
-    """One page's online-softmax update of the (1, D) accumulator.
-    s: (page,) masked logits; v: (page, D) float32 values."""
-    m_prev = m_sc[0]
-    m_new = jnp.maximum(m_prev, s.max())
+def online_softmax_update(q, k, v, mask, scale, acc, m_sc, l_sc):
+    """One KV tile's online-softmax update of a (rows, D) accumulator.
+    q (rows, D), k/v (cols, D) float32; `mask` broadcasts to (rows, cols);
+    acc/m_sc/l_sc are (rows, D) / (rows, 1) / (rows, 1) VMEM refs."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_sc[0] = l_sc[0] * alpha + p.sum()
-    m_sc[0] = m_new
-    acc[...] = acc[...] * alpha + (p[:, None] * v).sum(axis=0)[None, :]
+    l_sc[...] = l_sc[...] * alpha + p.sum(axis=1, keepdims=True)
+    m_sc[...] = m_new
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
 
-def _kernel(*refs, page: int, scale: float, n_pages: int, rep: int,
-            sz_mode: str):
+def dequant_head(x, sz_ref, g, sz_mode):
+    """Dequantize head `g` of a gathered tile. `sz_ref` is the page's
+    (1, KV, 2) per-page block or (1, rows, KV, 2) per-token block."""
     if sz_mode == "page":
-        (bt_ref, len_ref, ksz_ref, vsz_ref, q_ref, k_ref, v_ref, o_ref,
-         acc, m_sc, l_sc) = refs
-    elif sz_mode == "token":
-        (bt_ref, len_ref, q_ref, k_ref, v_ref, ksz_ref, vsz_ref, o_ref,
-         acc, m_sc, l_sc) = refs
-    else:
+        return x * sz_ref[0, g, 0] + sz_ref[0, g, 1]   # SMEM scalars
+    sz = sz_ref[0, :, g, :]                             # (rows, 2)
+    return x * sz[:, 0:1] + sz[:, 1:2]
+
+
+def _kernel(*refs, page: int, scale: float, n_pages: int, kv: int,
+            sz_mode: str):
+    if sz_mode == "none":
         (bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
          acc, m_sc, l_sc) = refs
+    else:
+        (bt_ref, len_ref, q_ref, k_ref, v_ref, ksz_ref, vsz_ref, o_ref,
+         acc, m_sc, l_sc) = refs
+    del bt_ref                       # chased by the index maps only
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
@@ -72,33 +97,23 @@ def _kernel(*refs, page: int, scale: float, n_pages: int, rep: int,
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)            # (D,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if sz_mode == "page":
-        # fused dequant epilogue: the page's (scale, zero) scalars sit in
-        # SMEM next to the block table entry that fetched it
-        pid = bt_ref[b, pi]
-        kvh = pl.program_id(1) // rep
-        k = k * ksz_ref[pid, kvh, 0] + ksz_ref[pid, kvh, 1]
-        v = v * vsz_ref[pid, kvh, 0] + vsz_ref[pid, kvh, 1]
-    elif sz_mode == "token":
-        # per-token sub-scales travel as VMEM tensor blocks next to the
-        # page payload (one (page, 2) tile per grid step, same
-        # bt-chasing index map), dequantized row-wise
-        k = k * ksz_ref[0, :, 0, 0][:, None] + ksz_ref[0, :, 0, 1][:, None]
-        v = v * vsz_ref[0, :, 0, 0][:, None] + vsz_ref[0, :, 0, 1][:, None]
-
-    s = (k @ q) * scale                               # (page,)
-    pos = pi * page + jax.lax.iota(jnp.int32, page)   # logical positions
-    valid = pos < len_ref[b]
-    s = jnp.where(valid, s, NEG_INF)
-    _flash_update(s, v, acc, m_sc, l_sc)
+    pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    valid = pos < len_ref[b]                          # (1, page)
+    for g in range(kv):
+        k = k_ref[0, :, g, :].astype(jnp.float32)     # (page, D)
+        v = v_ref[0, :, g, :].astype(jnp.float32)
+        if sz_mode != "none":
+            # fused dequant epilogue on the freshly gathered page
+            k = dequant_head(k, ksz_ref, g, sz_mode)
+            v = dequant_head(v, vsz_ref, g, sz_mode)
+        q = q_ref[0, g].astype(jnp.float32)           # (rep, D)
+        online_softmax_update(q, k, v, valid, scale, acc.at[g],
+                              m_sc.at[g], l_sc.at[g])
 
     @pl.when(pi == n_pages - 1)
     def _done():
-        o_ref[0, 0, :] = (
-            acc[0] / jnp.maximum(l_sc[0], 1e-30)
+        o_ref[0] = (
+            acc[...] / jnp.maximum(l_sc[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
@@ -116,10 +131,9 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
     With `k_sz`/`v_sz` float32 (scale, zero) arrays, the pool payload is
     int8 and the kernel dequantizes each gathered page in the epilogue
     (`repro.kernels.quant` layout). The sz grain dispatches on rank:
-    per-page (P_phys, KV, 2) rides the scalar-prefetch channel;
-    per-token (P_phys, page, KV, 2) — the speculative-decoding
-    sub-scale layout — travels as regular tensor operands whose
-    BlockSpec chases the same block-table entry as the payload."""
+    per-page (P_phys, KV, 2) or per-token (P_phys, page, KV, 2) — the
+    speculative-decoding sub-scale layout; either travels as a block
+    whose BlockSpec chases the same block-table entry as the payload."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
@@ -136,52 +150,45 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     block_tables = jnp.asarray(block_tables, jnp.int32)
 
-    page_spec = pl.BlockSpec(
-        (1, page, 1, D),
-        (lambda b, h, pi, bt, ln, *sz, rep=rep: (bt[b, pi], 0, h // rep, 0)),
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, D),
-                     lambda b, h, pi, bt, ln, *sz: (b, h, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = (q, k_pages, v_pages)
-    if sz_mode == "token":
+    def page_map(b, pi, bt, ln):
+        return (bt[b, pi],) + (0,) * (k_pages.ndim - 1)
+
+    q_spec = pl.BlockSpec((1, KV, rep, D), lambda b, pi, bt, ln: (b, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, page, KV, D), page_map)
+    in_specs = [q_spec, page_spec, page_spec]
+    operands = (q.reshape(B, KV, rep, D), k_pages, v_pages)
+    if sz_mode != "none":
+        sz_block = (1,) + tuple(jnp.shape(k_sz)[1:])
         sz_spec = pl.BlockSpec(
-            (1, page, 1, 2),
-            (lambda b, h, pi, bt, ln, rep=rep: (bt[b, pi], 0, h // rep, 0)),
-        )
+            sz_block,
+            lambda b, pi, bt, ln: (bt[b, pi],) + (0,) * (len(sz_block) - 1),
+            # a page's (KV, 2) scalars are read one by one: SMEM
+            memory_space=pltpu.SMEM if sz_mode == "page" else None)
         in_specs += [sz_spec, sz_spec]
         operands += (jnp.asarray(k_sz, jnp.float32),
                      jnp.asarray(v_sz, jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # block tables + lengths (+ per-page k/v (scale, zero) when int8)
-        num_scalar_prefetch=4 if sz_mode == "page" else 2,
-        grid=(B, H, n_pages),
+        num_scalar_prefetch=2,                   # block tables + lengths
+        grid=(B, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, D),
-                               lambda b, h, pi, bt, ln, *sz: (b, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((KV, rep, D), jnp.float32),
+            pltpu.VMEM((KV, rep, 1), jnp.float32),
+            pltpu.VMEM((KV, rep, 1), jnp.float32),
         ],
     )
-    scalars = (block_tables, lengths)
-    if sz_mode == "page":
-        scalars += (jnp.asarray(k_sz, jnp.float32),
-                    jnp.asarray(v_sz, jnp.float32))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, page=page, scale=scale, n_pages=n_pages,
-                          rep=rep, sz_mode=sz_mode),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+                          kv=KV, sz_mode=sz_mode),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rep, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            # MEGACORE partitioning: batch and head grid dimensions are
-            # "parallel" so Mosaic splits them across TensorCores; only
-            # the page dimension is sequential (online-softmax carry)
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # MEGACORE partitioning: the batch grid dimension is
+            # "parallel" so Mosaic splits it across TensorCores; only the
+            # page dimension is sequential (online-softmax carry)
+            dimension_semantics=("parallel", "arbitrary"),
         ) if not interpret else None,
-    )(*scalars, *operands)
+    )(block_tables, lengths, *operands)
+    return out.reshape(B, H, D)

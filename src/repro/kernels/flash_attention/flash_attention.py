@@ -2,7 +2,10 @@
 
 Grid (B, H, nq, nk) with the kv dimension sequential; online-softmax
 accumulators (acc, m, l) live in VMEM scratch across kv iterations. Blocks
-are MXU-aligned (block_q x D) / (block_k x D); fully-masked causal tiles are
+are MXU-aligned (block_q x D) / (block_k x D): the wrapper presents q/k/v
+head-major, (B, H, S, D), so each block's minor dims are (tokens, D) as
+Mosaic's tiling needs (a (block, 1, D) slice of the token-major layout
+would cut the head dim). Fully-masked causal tiles are
 skipped (`pl.when`), which is the 2x causal-waste saving the jnp chunked
 path cannot express. Backward reuses the chunked-jnp flash backward via
 custom_vjp (recompute-from-lse; the standard pairing).
@@ -39,9 +42,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
 
     @pl.when(needed)
     def _tile():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)             # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)             # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -68,8 +71,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc, *,
     @pl.when(ki == nk - 1)
     def _done():
         l_safe = jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_sc[...] + jnp.log(l_safe))[:, 0]
+        o_ref[0, 0] = (acc[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_sc[...] + jnp.log(l_safe)     # (bq, 1)
 
 
 def _fwd_pallas(q, k, v, causal, scale, kv_offset, block_q, block_k,
@@ -90,30 +93,24 @@ def _fwd_pallas(q, k, v, causal, scale, kv_offset, block_q, block_k,
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
         )
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D),
+                           lambda b, h, qi, ki: (b, h // rep, ki, 0))
+    head_major = (0, 2, 1, 3)
     out, lse = pl.pallas_call(
         functools.partial(
             _kernel, block_q=bq, block_k=bk, nk=nk, rep=rep, scale=scale,
             causal=causal, kv_offset=kv_offset,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec(
-                (1, bk, 1, D),
-                lambda b, h, qi, ki, rep=rep: (b, ki, h // rep, 0),
-            ),
-            pl.BlockSpec(
-                (1, bk, 1, D),
-                lambda b, h, qi, ki, rep=rep: (b, ki, h // rep, 0),
-            ),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=(
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            q_spec,
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
@@ -122,8 +119,9 @@ def _fwd_pallas(q, k, v, causal, scale, kv_offset, block_q, block_k,
         ],
         interpret=interpret,
         **kwargs,
-    )(q, k, v)
-    return out, lse
+    )(q.transpose(head_major), k.transpose(head_major),
+      v.transpose(head_major))
+    return out.transpose(head_major), lse[..., 0]
 
 
 @functools.partial(

@@ -2,9 +2,8 @@
 
 Reference backend scatters with jnp (`ref.write_pages`); pallas/interpret
 run the aliased in-place kernel, so the serving prefill-insert cell
-issues zero standalone page-scatter ops on the kernel backends. Arbitrary
-trailing dims are flattened to one lane dim around the kernel (the
-reshapes are layout no-ops on contiguous pools)."""
+issues zero standalone page-scatter ops on the kernel backends. The
+kernel copies whole (*page_dims) tiles in the pool's own layout."""
 
 from __future__ import annotations
 
@@ -29,10 +28,5 @@ def write_pages(pool, tiles, phys, *, impl: Optional[str] = None):
         return ref.write_pages(pool, tiles, phys)
     from repro.kernels.page_io import page_io
 
-    nb, P = pool.shape[:2]
-    n_wp = tiles.shape[1]
-    out = page_io.write_pages_pallas(
-        pool.reshape(nb, P, -1), tiles.reshape(nb, n_wp, -1), phys,
-        interpret=interpret,
-    )
-    return out.reshape(pool.shape)
+    return page_io.write_pages_pallas(pool, tiles, phys,
+                                      interpret=interpret)

@@ -7,6 +7,13 @@ term, and the dX^T @ (w o B) state update — so the sequential component is
 only the O(n_chunks) scalar-decay recurrence, exactly the SSD decomposition
 (arXiv:2405.21060) mapped onto the TPU memory hierarchy.
 
+Layout: the wrapper presents every per-head operand head-major, (B, H, S,
+·), so each block's minor dims are (Q, ·) as Mosaic's tiling needs, and
+passes the step sizes `dt` twice — as a (1, Q) row and a (Q, 1) column —
+so the in-chunk cumulative decay is built from masked (Q, Q) sums in
+either orientation without a vector transpose. A and D are whole (H,)
+SMEM arrays read by scalar index.
+
 Backward: custom_vjp differentiates the (numerically identical) chunked-jnp
 implementation.
 """
@@ -23,51 +30,54 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ssd_scan import chunked as cj
 
 
-def _kernel(A_ref, D_ref, x_ref, dt_ref, B_ref, C_ref, h0_ref,
+def _kernel(A_ref, D_ref, x_ref, dtr_ref, dtc_ref, B_ref, C_ref, h0_ref,
             y_ref, hT_ref, state, *, Q: int, nc: int, has_D: bool):
+    h = pl.program_id(1)
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _init():
         state[...] = h0_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)           # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)            # (Q,)
-    Bm = B_ref[0, :, 0, :].astype(jnp.float32)          # (Q, N)
-    Cm = C_ref[0, :, 0, :].astype(jnp.float32)          # (Q, N)
-    A = A_ref[0]
+    x = x_ref[0, 0].astype(jnp.float32)                 # (Q, P)
+    dt_c = dtc_ref[0, 0].astype(jnp.float32)            # (Q, 1)
+    Bm = B_ref[0, 0].astype(jnp.float32)                # (Q, N)
+    Cm = C_ref[0, 0].astype(jnp.float32)                # (Q, N)
+    A = A_ref[h]
 
-    a = A * dt                                          # (Q,) <= 0
-    cum = jnp.cumsum(a)                                 # (Q,)
-    total = cum[-1]
+    a_r = A * dtr_ref[0, 0].astype(jnp.float32)         # (1, Q) <= 0
+    a_c = A * dt_c                                      # (Q, 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    # inclusive cumulative sums of a, as a column and as a row
+    cum_c = jnp.where(jj <= ii, a_r, 0.0).sum(axis=1, keepdims=True)
+    cum_r = jnp.where(ii <= jj, a_c, 0.0).sum(axis=0, keepdims=True)
+    total = a_r.sum(axis=1, keepdims=True)              # (1, 1)
 
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                   # (Q, Q)  i x j
-    decay = cum[:, None] - cum[None, :]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(ii >= jj, jnp.exp(decay), 0.0)
-    dx = dt[:, None] * x                                # (Q, P)
+    L = jnp.where(ii >= jj, jnp.exp(cum_c - cum_r), 0.0)
+    dx = dt_c * x                                       # (Q, P)
     y = jax.lax.dot_general(
         scores * L, dx, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                   # intra (Q, P)
 
     # inter-chunk: y += exp(cum) * C @ state^T
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(
         Cm, state[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     if has_D:
-        y = y + D_ref[0] * x
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+        y = y + D_ref[h] * x
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: h = exp(total) h + dx^T @ (w o B)
-    w = jnp.exp(total - cum)                            # (Q,)
+    w = jnp.exp(total - cum_c)                          # (Q, 1)
     hc = jax.lax.dot_general(
-        dx, w[:, None] * Bm, (((0,), (0,)), ((), ())),
+        dx, w * Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                   # (P, N)
     state[...] = jnp.exp(total) * state[...] + hc
@@ -93,35 +103,39 @@ def _ssd_fwd_pallas(x, dt, A, Bmat, Cmat, D, init_state, chunk, interpret):
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         )
+    head_major = (0, 2, 1, 3)
+    dt_hm = dt.transpose(0, 2, 1)                       # (B, H, S)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)        # whole (H,) array
     y, hT = pl.pallas_call(
         functools.partial(_kernel, Q=Q, nc=nc, has_D=has_D),
         out_shape=(
-            jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ),
         grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, c: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, c: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, Q, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
-            pl.BlockSpec((1, Q, 1, N),
-                         lambda b, h, c, rep=rep: (b, c, h // rep, 0)),
+            smem,
+            smem,
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Q, N),
+                         lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
+            pl.BlockSpec((1, 1, Q, N),
+                         lambda b, h, c, rep=rep: (b, h // rep, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(A.astype(jnp.float32), D_arr, x, dt, Bmat, Cmat, init_state)
-    return y, hT
+    )(A.astype(jnp.float32), D_arr, x.transpose(head_major),
+      dt_hm[:, :, None, :], dt_hm[..., None],
+      Bmat.transpose(head_major), Cmat.transpose(head_major), init_state)
+    return y.transpose(head_major), hT
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))

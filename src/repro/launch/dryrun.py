@@ -1,4 +1,6 @@
 import os, sys  # noqa: E401  (brief: set XLA_FLAGS before ANY other import)
+# a forced-host-device dry-run by design: never reach for an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=" + os.environ.get("REPRO_DEVICES", "512" if "--multi-pod" in sys.argv else "256")).strip()  # noqa: E501
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
@@ -138,8 +140,6 @@ def run_cell(arch: str, shape_name: str, mesh, args, outdir: str):
         ma = compiled.memory_analysis()
         print(ma)                               # proves it fits
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax < 0.5: one dict per program
-            ca = ca[0] if ca else {}
         print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
         cost = analyze_hlo(compiled.as_text())
         rep = roofline.report(

@@ -21,6 +21,7 @@ import argparse
 import numpy as np
 
 from repro import configs
+from repro.common import compile_cache
 from repro.launch.mesh import ctx_for_mesh, make_smoke_mesh
 from repro.serving import (
     EngineConfig,
@@ -97,6 +98,7 @@ def main(argv=None):
     ap.add_argument("--admission", default="loi",
                     choices=["loi", "greedy"])
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(
         args.arch

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
+from repro.common.parallel import per_kv_shard
 from repro.kernels.decode_attention import ops as decode_ops
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.models.layers import apply_rope
@@ -293,6 +294,7 @@ def paged_decode_self_attention(
     block_table,            # (B, n_pages) int32
     page_tokens: int,
     rope: bool = True,
+    ctx=None,
 ):
     """Single-token decode against the paged cache: insert new KV through
     the block table, gather-attend via the paged decode kernel. Same
@@ -300,7 +302,9 @@ def paged_decode_self_attention(
     write nothing — but the cache IS the physical page pool the serving
     pager allocates from, so tier placement is real at the data layout.
     Block-quantized pools (the "k_sz"/"v_sz" leaves) quantize on insert
-    and dequantize inside the kernel. Returns (out, cache_updates)."""
+    and dequantize inside the kernel. Under a mesh the kernel runs per
+    tp shard (`common.parallel.per_kv_shard`). Returns
+    (out, cache_updates)."""
     B = x.shape[0]
     t = jnp.asarray(t)
     t_vec = t if t.ndim else jnp.full((B,), t)
@@ -312,9 +316,12 @@ def paged_decode_self_attention(
             cache["k"], cache["k_sz"], k, t_vec, block_table, page_tokens)
         v_pool, v_sz = paged_quant_cache_insert(
             cache["v"], cache["v_sz"], v, t_vec, block_table, page_tokens)
-        out = decode_ops.paged_decode_mha(
-            q[:, 0], k_pool, v_pool, block_table, t_vec + 1,
-            k_sz=k_sz, v_sz=v_sz,
+        out = per_kv_shard(
+            ctx, cfg.num_kv_heads,
+            lambda q, kp, vp, ks, vs, bt, ln: decode_ops.paged_decode_mha(
+                q, kp, vp, bt, ln, k_sz=ks, v_sz=vs),
+            (q[:, 0], k_pool, v_pool, k_sz, v_sz),
+            (block_table, t_vec + 1),
         )
         updates = {"k": k_pool, "v": v_pool, "k_sz": k_sz, "v_sz": v_sz}
     else:
@@ -322,8 +329,9 @@ def paged_decode_self_attention(
                                     page_tokens)
         v_pool = paged_cache_insert(cache["v"], v, t_vec, block_table,
                                     page_tokens)
-        out = decode_ops.paged_decode_mha(
-            q[:, 0], k_pool, v_pool, block_table, t_vec + 1
+        out = per_kv_shard(
+            ctx, cfg.num_kv_heads, decode_ops.paged_decode_mha,
+            (q[:, 0], k_pool, v_pool), (block_table, t_vec + 1),
         )
         updates = {"k": k_pool, "v": v_pool}
     out = jnp.einsum("bhk,hkd->bd", out, params["wo"].astype(x.dtype))
@@ -340,6 +348,7 @@ def paged_prefill_chunk_attention(
     block_row,              # (1, n_pages) the slot's block-table row
     page_tokens: int,
     rope: bool = True,
+    ctx=None,
 ):
     """One prompt chunk against the paged cache via the FUSED
     insert+attend kernel: the chunk's K/V (int8 pools: pre-quantized
@@ -351,11 +360,13 @@ def paged_prefill_chunk_attention(
     chunk through HBM per layer — does not exist on the kernel backends
     (the reference backend runs the unfused oracle). C and c0 must be
     page-aligned (the engine enforces `prefill_chunk % page_tokens ==
-    0`). Returns (out, cache_updates)."""
+    0`). Under a mesh the kernel runs per tp shard. Returns
+    (out, cache_updates)."""
     B, C, _ = x.shape
     c0 = jnp.asarray(c0, jnp.int32)
     positions = c0 + jnp.broadcast_to(jnp.arange(C)[None, :], (B, C))
     q, k, v = _qkv(params, x, cfg, positions, rope)
+    n_kv = cfg.num_kv_heads
     quantized = "k_sz" in cache
     if quantized and cache["k_sz"].ndim == cache["k"].ndim:
         # per-token sub-scale pool (speculative decoding): quantize each
@@ -375,8 +386,11 @@ def paged_prefill_chunk_attention(
                                   page_tokens)
         v_sz = paged_chunk_insert(cache["v_sz"], vsz, c0, block_row,
                                   page_tokens)
-        out = flash_ops.paged_prefill_mha(
-            q, k_pool, v_pool, block_row, c0, k_sz=k_sz, v_sz=v_sz,
+        out = per_kv_shard(
+            ctx, n_kv,
+            lambda q, kp, vp, ks, vs, bt, c0: flash_ops.paged_prefill_mha(
+                q, kp, vp, bt, c0, k_sz=ks, v_sz=vs),
+            (q, k_pool, v_pool, k_sz, v_sz), (block_row, c0),
         )
         updates = {"k": k_pool, "v": v_pool, "k_sz": k_sz, "v_sz": v_sz}
     elif quantized:
@@ -388,15 +402,17 @@ def paged_prefill_chunk_attention(
             k.reshape(B, n_wp, page_tokens, KV, hd))
         v8, vsz = quant.quantize_pages(
             v.reshape(B, n_wp, page_tokens, KV, hd))
-        out, k_pool, v_pool, k_sz, v_sz = flash_ops.paged_prefill_insert_mha_q8(
-            q, cache["k"], cache["v"], cache["k_sz"], cache["v_sz"],
-            k8.reshape(B, C, KV, hd), v8.reshape(B, C, KV, hd),
-            ksz, vsz, block_row, c0,
+        out, k_pool, v_pool, k_sz, v_sz = per_kv_shard(
+            ctx, n_kv, flash_ops.paged_prefill_insert_mha_q8,
+            (q, cache["k"], cache["v"], cache["k_sz"], cache["v_sz"],
+             k8.reshape(B, C, KV, hd), v8.reshape(B, C, KV, hd), ksz, vsz),
+            (block_row, c0),
         )
         updates = {"k": k_pool, "v": v_pool, "k_sz": k_sz, "v_sz": v_sz}
     else:
-        out, k_pool, v_pool = flash_ops.paged_prefill_insert_mha(
-            q, cache["k"], cache["v"], k, v, block_row, c0,
+        out, k_pool, v_pool = per_kv_shard(
+            ctx, n_kv, flash_ops.paged_prefill_insert_mha,
+            (q, cache["k"], cache["v"], k, v), (block_row, c0),
         )
         updates = {"k": k_pool, "v": v_pool}
     out = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))

@@ -246,21 +246,18 @@ def init_paged_caches(cfg: ModelConfig, n_slots: int, max_seq: int,
 PAGED_LEAF_NAMES = ("k", "v", "k_sz", "v_sz")
 
 
-def init_pool_twin(caches):
-    """Pool-resident twin of the PAGED leaves of `caches`: a same-shape
-    zeros tree holding only the physical page pool arrays (k/v payload
-    plus the int8 (scale, zero) leaves). The serving substrate
-    (`repro.serving.substrate`) places it — `pinned_host` NamedSharding
-    in physical mode, default memory when emulated — and mirrors
-    pool-tiered pages into it via the jitted transfer streams. Returns
-    {} for cache trees with no paged leaves (SSM-only stacks)."""
-    twin = {}
+def paged_pool_leaves(caches):
+    """The PAGED leaves of `caches`: the physical page pool arrays (k/v
+    payload plus the int8 (scale, zero) leaves), by layer position. The
+    serving substrate (`repro.serving.substrate`) mirrors pool-tiered
+    pages of these into its host twin. Returns {} for cache trees with
+    no paged leaves (SSM-only stacks)."""
+    pool = {}
     for pos, c in caches.items():
-        sub = {name: jnp.zeros(c[name].shape, c[name].dtype)
-               for name in PAGED_LEAF_NAMES if name in c}
+        sub = {name: c[name] for name in PAGED_LEAF_NAMES if name in c}
         if sub:
-            twin[pos] = sub
-    return twin
+            pool[pos] = sub
+    return pool
 
 
 def _apply_layer_decode(p, c, x, t, cfg: ModelConfig, desc: LayerDesc,
@@ -283,7 +280,7 @@ def _apply_layer_decode(p, c, x, t, cfg: ModelConfig, desc: LayerDesc,
             nc.update(updates)
         elif block_table is not None:
             h, updates = attn.paged_decode_self_attention(
-                p["attn"], h, cfg, c, t, block_table, page_tokens,
+                p["attn"], h, cfg, c, t, block_table, page_tokens, ctx=ctx,
             )
             nc.update(updates)
         else:
@@ -348,7 +345,7 @@ def stack_prefill_chunk(params, caches, x, c0, cfg: ModelConfig,
 
     def chunk_attn(p_attn, h, c):
         return attn.paged_prefill_chunk_attention(
-            p_attn, h, cfg, c, c0, block_row, page_tokens
+            p_attn, h, cfg, c, c0, block_row, page_tokens, ctx=ctx
         )
 
     def body(x, inp):

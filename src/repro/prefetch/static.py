@@ -32,7 +32,7 @@ from repro.runtime import capability
 
 def to_device(x):
     if capability.supports_internal_transfer():
-        return jax.device_put(x, jax.memory.TransferToMemoryKind("device"))
+        return jax.device_put(x, jax.memory.Space.Device)
     return x
 
 

@@ -1,10 +1,12 @@
 """Backend capability probes for memory-kind (tier) annotations.
 
 XLA:TPU supports pinned_host placement on inputs, outputs and internal
-transfers; XLA:CPU (this container) accepts pinned_host *inputs* but hits
-UNIMPLEMENTED on output placement annotations. The tier engine degrades
-gracefully: placements are always tracked logically; physical annotations are
-applied per capability.
+transfers; XLA:CPU accepts pinned_host inputs and internal transfers but
+hits UNIMPLEMENTED on output placement annotations. The tier engine degrades on the CPU only:
+placements are always tracked logically, and physical annotations are
+applied per capability. On a TPU a probe that raises is a fault and
+surfaces — it never reads as "unsupported", which would quietly turn real
+HBM<->host page moves into counted bytes.
 """
 
 from __future__ import annotations
@@ -16,58 +18,58 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
+def _probe(check) -> bool:
+    """True when `check()` compiles. On the CPU backend a refusal means
+    "unsupported"; on a TPU it is an error and is raised."""
+    try:
+        check()
+    except Exception:
+        if jax.default_backend() == "tpu":
+            raise
+        return False
+    return True
+
+
+def _host_sharding():
+    mesh = jax.sharding.Mesh(jax.devices()[:1], ("x",))
+    return NamedSharding(mesh, P(), memory_kind="pinned_host")
+
+
 @functools.cache
 def supports_host_input() -> bool:
-    try:
-        mesh = jax.sharding.Mesh(jax.devices()[:1], ("x",))
-        s = NamedSharding(mesh, P(), memory_kind="pinned_host")
-        x = jax.ShapeDtypeStruct((8,), jnp.float32)
-        jax.jit(lambda a: a * 2, in_shardings=s).lower(x).compile()
-        return True
-    except Exception:
-        return False
+    """A pinned_host input read into device memory (page_in's pattern:
+    XLA:TPU computes on device-memory operands only)."""
+    x = jax.ShapeDtypeStruct((8,), jnp.float32)
+    return _probe(lambda: jax.jit(
+        lambda a: jax.device_put(a, jax.memory.Space.Device) * 2,
+        in_shardings=_host_sharding()).lower(x).compile())
 
 
 @functools.cache
 def supports_host_output() -> bool:
-    try:
-        mesh = jax.sharding.Mesh(jax.devices()[:1], ("x",))
-        s = NamedSharding(mesh, P(), memory_kind="pinned_host")
-        x = jax.ShapeDtypeStruct((8,), jnp.float32)
-        jax.jit(lambda a: a * 2, out_shardings=s).lower(x).compile()
-        return True
-    except Exception:
-        return False
+    x = jax.ShapeDtypeStruct((8,), jnp.float32)
+    return _probe(lambda: jax.jit(
+        lambda a: a * 2, out_shardings=_host_sharding()).lower(x).compile())
 
 
 @functools.cache
 def supports_internal_transfer() -> bool:
-    try:
-        x = jnp.ones((8,))
+    def f(a):
+        b = jax.device_put(a, jax.memory.Space.Host)
+        return jax.device_put(b, jax.memory.Space.Device) * 2
 
-        def f(a):
-            b = jax.device_put(
-                a, jax.memory.TransferToMemoryKind("pinned_host")
-            )
-            return jax.device_put(
-                b, jax.memory.TransferToMemoryKind("device")
-            ) * 2
-
-        jax.jit(f).lower(x).compile()
-        return True
-    except Exception:
-        return False
+    return _probe(lambda: jax.jit(f).lower(jnp.ones((8,))).compile())
 
 
 def resolve_substrate_mode(requested: str, *, host_input: bool,
                            host_output: bool, internal: bool) -> str:
     """Pure mode resolution for the physical KV substrate.
 
-    The substrate keeps a host-resident twin of the pool pages: it needs
-    pinned_host *placement* of standing arrays (host_input — the twin is
-    an input to nothing but device_put, but placement uses the same
-    compile path) and jittable internal transfers for the page streams.
-    host_output alone is not enough (can't round-trip pages back out).
+    The substrate keeps a host-resident twin of the pool pages: page_in
+    reads it as a pinned_host input (host_input), page_out returns it
+    with a pinned_host output sharding (host_output), and both move each
+    page with an in-program transfer between memory kinds (internal).
+    All three are needed.
 
       requested="physical"  — demand the real thing; raise if unsupported
       requested="emulated"  — force default-memory twin (same code shape,
@@ -82,7 +84,7 @@ def resolve_substrate_mode(requested: str, *, host_input: bool,
             f"'physical')")
     if requested in ("off", "emulated"):
         return requested
-    physical_ok = host_input and internal
+    physical_ok = host_input and host_output and internal
     if requested == "physical":
         if not physical_ok:
             raise RuntimeError(

@@ -95,9 +95,9 @@ the identical logical->physical page mapping and gathers only its own
 head slice, so the page allocator stays a single host-side object and
 the token stream is bit-identical to the single-device engine (the CI
 `sharded-parity` lane forces an 8-device host mesh and asserts exactly
-that). Interpret-mode pallas lowers the paged kernels to plain HLO, so
-GSPMD partitions them like any jnp program; compiled-TPU kernel
-partitioning rides the same shardings.
+that). A compiled Mosaic kernel cannot be partitioned by GSPMD, so the
+paged decode, chunk and page-write kernels run per tp shard under
+`shard_map` (`common.parallel.per_kv_shard`) on every backend.
 
 TIER LAYOUT / TRANSFER-STREAM CONTRACT (`repro.serving.substrate`):
 the cells only ever touch the DEVICE pool — the authoritative copy.
@@ -122,7 +122,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.common.config import ModelConfig
-from repro.common.parallel import ParallelCtx
+from repro.common.parallel import ParallelCtx, per_kv_shard
 from repro.models import model as M
 from repro.models.module import shape_mode
 from repro.runtime import sharding as shd
@@ -142,6 +142,24 @@ def abstract_params(cfg: ModelConfig, serve_dtype: bool = True):
 
         params = jax.tree.map(cast, params)
     return params, axes
+
+
+def init_params(cfg: ModelConfig, seed: int, shardings=None):
+    """Serving weights made from `seed`, in the serving dtype
+    (`abstract_params`), built under jit straight into `shardings` (the
+    cells' param shardings; None = default placement) — a model that
+    needs the mesh never has its whole float32 tree on one device."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def init():
+        params, _ = M.init_model(cfg, jax.random.PRNGKey(seed))
+        return jax.tree.map(
+            lambda p: p.astype(dt)
+            if jnp.issubdtype(p.dtype, jnp.floating) else p,
+            params,
+        )
+
+    return jax.jit(init, out_shardings=shardings)()
 
 
 def abstract_caches(cfg: ModelConfig, batch: int, max_seq: int,
@@ -371,7 +389,8 @@ def build_decode_draft(cfg: ModelConfig, ctx: ParallelCtx):
 
 def build_paged_cache_insert(bucket_total: int, page_tokens: int,
                              pool_dtype: str = "fp",
-                             sz_granularity: str = "page"):
+                             sz_granularity: str = "page",
+                             ctx: ParallelCtx | None = None):
     """Land a prefilled request's caches in the PAGED layout: the
     request's `bucket_total` tokens of K/V (batch=1, dense from the
     prefill cell) go whole-page into the physical pool at the pages the
@@ -390,7 +409,8 @@ def build_paged_cache_insert(bucket_total: int, page_tokens: int,
     they cannot pollute the page's range). With
     `sz_granularity="token"` the prompt pages get per-token sub-scales
     instead (`kernels.quant.quantize_tokens`) and the (page_tokens, KV,
-    2) sz tiles land through the same generic page writer."""
+    2) sz tiles land through the same generic page writer. Under a mesh
+    the writer runs per tp shard (`common.parallel.per_kv_shard`)."""
     from repro.kernels import quant
     from repro.kernels.page_io import ops as page_ops
 
@@ -411,6 +431,10 @@ def build_paged_cache_insert(bucket_total: int, page_tokens: int,
             return jax.lax.dynamic_update_slice(
                 big, small.astype(big.dtype), idx
             )
+
+        def write(pool, tiles):
+            return per_kv_shard(ctx, pool.shape[-2], page_ops.write_pages,
+                                (pool, tiles), (phys,))
 
         def page_tiles(small):
             sm = small[:, 0]                   # (nb, bucket_total, KV, hd)
@@ -436,12 +460,10 @@ def build_paged_cache_insert(bucket_total: int, page_tokens: int,
                         q8, sz_rows = quant.quantize_tokens(tiles)
                     else:
                         q8, sz_rows = quant.quantize_pages(tiles)
-                    oc[key] = page_ops.write_pages(c[key], q8, phys)
-                    oc[key + "_sz"] = page_ops.write_pages(
-                        c[key + "_sz"], sz_rows, phys
-                    )
+                    oc[key] = write(c[key], q8)
+                    oc[key + "_sz"] = write(c[key + "_sz"], sz_rows)
                 else:
-                    oc[key] = page_ops.write_pages(c[key], tiles, phys)
+                    oc[key] = write(c[key], tiles)
             out[pos] = oc
         return out
 
@@ -717,7 +739,7 @@ def make_engine_cells(cfg: ModelConfig, ctx: ParallelCtx,
         cell = build_prefill_greedy(cfg, ctx, b)
         ins_cell = (
             build_paged_cache_insert(b + npfx, page_tokens, pool_dtype,
-                                     sz_granularity)
+                                     sz_granularity, ctx)
             if paged else build_cache_insert()
         )
         if mesh is not None:
@@ -785,13 +807,7 @@ def make_engine_cells(cfg: ModelConfig, ctx: ParallelCtx,
         # draft tree through the shared EngineCells.
         draft_fn = jax.jit(build_decode_draft(draft_cfg, ctx),
                            donate_argnums=(2,))
-        dparams, _ = M.init_model(draft_cfg, jax.random.PRNGKey(0))
-        ddt = jnp.dtype(draft_cfg.dtype)
-        draft_params = jax.tree.map(
-            lambda p: p.astype(ddt)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p,
-            dparams,
-        )
+        draft_params = init_params(draft_cfg, 0)
 
     return EngineCells(
         decode_fn=decode,

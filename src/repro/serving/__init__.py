@@ -106,17 +106,18 @@ shared by n slots occupies ONE page of budget (`phys_tiers()`,
 PHYSICAL SUBSTRATE (`serving/substrate/`, `EngineConfig.substrate`):
 the pager's local/pool tier map stops being bookkeeping and becomes
 physical placement. `TierSubstrate` owns a host-resident TWIN of the
-paged pool leaves (`models.blocks.init_pool_twin`) placed through
+paged pool leaves (`models.blocks.paged_pool_leaves`), laid out one
+tile-aligned row block per page and placed through
 `runtime.sharding.named(..., memory_kind=...)` — pinned_host where the
 backend supports it ("physical" mode), default memory with identical
 program shapes where it doesn't ("emulated", the XLA:CPU CI fallback;
 `runtime.capability.substrate_mode` resolves "auto" per backend probe).
 Each decode step the engine drains the substrate: the pager's pool page
 set is diffed against the twin's residency and reconciled with jitted
-async transfer STREAMS — page_out (device pool -> twin, donated twin
-scatter), page_in (twin gather -> device, promotion), drop (freed, no
-bytes move) — every stream recorded in a completion-tracked
-`SubstrateLedger` whose `page_bytes` are MEASURED from the twin arrays'
+async transfer STREAMS — page_out (device pool -> twin, one DMA per
+page into the donated twin), page_in (twin -> device, promotion), drop
+(freed, no bytes move) — every stream recorded in a completion-tracked
+`SubstrateLedger` whose `page_bytes` are MEASURED from the pool arrays'
 nbytes. Contract (bench-gated): after every drain,
 `KVPager.pool_bytes_used() == ledger.placement_bytes()` — the virtual
 clock prices exactly the bytes that physically moved. Fleet handoffs

@@ -586,7 +586,7 @@ class ServingEngine:
             draft_cfg=draft_cfg,
         )
         if params is None:
-            params, _ = M.init_model(cfg, jax.random.PRNGKey(seed))
+            params = serve_rt.init_params(cfg, seed, cells.param_shardings)
         return cls(cfg, ctx, ecfg, params, cells, topo=topo)
 
     def _enc_len(self) -> int:

@@ -11,9 +11,10 @@ split:
                   device memory: every kernel keeps reading the same
                   arrays, so token streams are bit-identical with the
                   substrate on or off.
-  host twin     — a same-shape zeros twin of the paged leaves
-                  (`models.blocks.init_pool_twin`), placed with a
-                  `pinned_host` NamedSharding when the backend supports
+  host twin     — a page-major twin of the paged leaves
+                  (`models.blocks.paged_pool_leaves`): one tile-aligned
+                  row block per page (see `tier_substrate`), placed with
+                  a `pinned_host` NamedSharding when the backend supports
                   it. Pages whose pager tier is POOL are mirrored here;
                   LOCAL and free pages are not.
 
@@ -25,26 +26,25 @@ streams —
 
   page_out  — newly pool-tiered pages (hot-tail eviction, cold-prefix
               demotion, static-policy spill, COW copies landing in the
-              pool) gather from the device pool and scatter into the
-              host twin in one jitted program whose output sharding IS
-              the twin's placement (a real device->host DMA stream in
-              physical mode).
-  page_in   — pool pages promoted back to LOCAL gather out of the twin
-              with a device-memory output sharding (host->device
-              stream); the device pool already holds the payload, so
-              the result is only held for completion tracking.
+              pool) gather from the device pool and land in the donated
+              host twin, one device->host DMA per page, in one jitted
+              program whose output sharding IS the twin's placement.
+  page_in   — pool pages promoted back to LOCAL come out of the twin
+              one host->device DMA per page, returned in the pool's own
+              layout; the device pool already holds the payload, so the
+              result is only held for completion tracking.
   drop      — pages freed while pool-resident (slot release, prefix
               trie reclaim) leave the twin with zero transfer bytes.
 
 Within-step churn (a page evicted and promoted between two drains)
 coalesces to its net placement change — the stream contract is
 placement-accurate, not event-replaying. Page-id vectors are padded to
-power-of-two lengths (repeating the last id: a duplicate scatter of
-identical data is a no-op) so the jitted transfer programs compile
+power-of-two lengths (repeating the last id: writing the same page
+twice with identical data is a no-op) so the jitted transfer programs compile
 O(log pool_size) times, not per distinct burst size.
 
 Every stream appends a `TransferEvent` to the `SubstrateLedger`:
-MEASURED bytes (leaf `nbytes` of the actual twin arrays, not the
+MEASURED bytes (leaf `nbytes` of the actual pool arrays, not the
 closed-form kv-byte walk), completion tracked via `sync()`
 (`block_until_ready` over the in-flight payloads — transfers are
 issued without blocking the step). The accounting contract, tested in
@@ -56,7 +56,7 @@ after every drain, in both modes.
 
 MODES (`runtime.capability.resolve_substrate_mode`): "physical" places
 the twin with `memory_kind="pinned_host"` and needs the backend's
-host-input + internal-transfer probes (XLA:TPU); "emulated" runs the
+host-input, host-output and internal-transfer probes (XLA:TPU); "emulated" runs the
 identical program shapes with default-memory placement (XLA:CPU, this
 CI) so the ledger, byte accounting and tests are the same everywhere;
 "auto" picks physical when the backend can; "off" disables the

@@ -1,9 +1,9 @@
 """Completion-tracked transfer ledger for the physical KV substrate.
 
 One `TransferEvent` per issued stream (see the package docstring for
-the stream kinds). `bytes` is measured from the actual twin arrays'
+the stream kinds). `bytes` is measured from the actual pool arrays'
 `nbytes` — `page_bytes` here is handed in by `TierSubstrate` as
-sum(leaf.nbytes / n_phys_pages) over the twin leaves, so the ledger
+sum(leaf.nbytes / n_phys_pages) over the paged pool leaves, so the ledger
 never re-derives footprint from model math. `placement_bytes()` is the
 running host-resident footprint the engine's `phys_tiers()` pool
 accounting must match after every drain.

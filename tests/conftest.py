@@ -1,6 +1,3 @@
-import sys
-import types
-
 import jax
 import pytest
 
@@ -9,91 +6,6 @@ import pytest
 # subprocesses; see test_multidevice.py).
 
 jax.config.update("jax_enable_x64", False)
-
-
-# ----------------------------------------------------- hypothesis fallback
-# Property tests use hypothesis when it is installed. On a bare environment
-# we register a miniature stand-in under the same module names BEFORE the
-# test modules import it, degrading each @given property test to a small
-# deterministic parametrized case sweep (seeded per case) instead of
-# erroring out at collection.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import numpy as _np
-
-    _N_FALLBACK_CASES = 20
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    def _floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(lambda rng: float(rng.uniform(min_value, max_value)))
-
-    def _integers(min_value=0, max_value=100, **_kw):
-        return _Strategy(
-            lambda rng: int(rng.integers(min_value, max_value + 1))
-        )
-
-    def _booleans():
-        return _Strategy(lambda rng: bool(rng.integers(2)))
-
-    def _sampled_from(seq):
-        pool = list(seq)
-        return _Strategy(lambda rng: pool[int(rng.integers(len(pool)))])
-
-    def _lists(elements, min_size=0, max_size=10, **_kw):
-        def draw(rng):
-            n = int(rng.integers(min_size, max_size + 1))
-            return [elements.draw(rng) for _ in range(n)]
-
-        return _Strategy(draw)
-
-    def _tuples(*elems):
-        return _Strategy(lambda rng: tuple(e.draw(rng) for e in elems))
-
-    def _given(*strategies, **kw_strategies):
-        def deco(fn):
-            def _case(_hyp_case):
-                rng = _np.random.default_rng(_hyp_case + 1)
-                args = [s.draw(rng) for s in strategies]
-                kwargs = {k: s.draw(rng) for k, s in kw_strategies.items()}
-                fn(*args, **kwargs)
-
-            _case.__name__ = fn.__name__
-            _case.__doc__ = fn.__doc__
-            _case.__module__ = fn.__module__
-            return pytest.mark.parametrize(
-                "_hyp_case", range(_N_FALLBACK_CASES)
-            )(_case)
-
-        return deco
-
-    def _settings(*_a, **_kw):
-        return lambda fn: fn
-
-    def _assume(condition):
-        if not condition:
-            pytest.skip("hypothesis-fallback: assume() rejected the case")
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = _assume
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.floats = _floats
-    _st.integers = _integers
-    _st.booleans = _booleans
-    _st.sampled_from = _sampled_from
-    _st.lists = _lists
-    _st.tuples = _tuples
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

@@ -82,3 +82,42 @@ def test_shapes_table():
     assert SHAPES["train_4k"].tokens == 4096 * 256
     assert SHAPES["long_500k"].seq_len == 524288
     assert SHAPES["decode_32k"].kind == "decode"
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+def test_device_kind_table_describes_v5e(kind):
+    from repro.common import hw
+
+    assert hw.chip_for(kind) is hw.V5E
+
+
+def test_unknown_device_kind_is_an_error():
+    from repro.common import hw
+
+    with pytest.raises(ValueError, match="not described"):
+        hw.chip_for("TPU v9 imaginary")
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The env var wins untouched; without it the cache sits at the fixed
+    `<checkout>/.jax_cache`. The config is restored before anything
+    compiles, so the test session stays cache-free."""
+    import os
+
+    import jax
+
+    from repro.common import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable()
+        assert path == os.path.join(compile_cache.CHECKOUT, ".jax_cache")
+        assert os.path.isfile(os.path.join(compile_cache.CHECKOUT,
+                                           "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -6,13 +6,15 @@ physical shape contract that keeps the CPU fallback honest."""
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
 from repro.common.parallel import ParallelCtx
 from repro.models import model as M
-from repro.models.blocks import PAGED_LEAF_NAMES, init_pool_twin
+from repro.models.blocks import PAGED_LEAF_NAMES, paged_pool_leaves
 from repro.runtime import capability
 from repro.serving import (
     EngineConfig,
@@ -70,18 +72,27 @@ def test_resolve_fixed_modes_ignore_probes(requested, host_input,
 ])
 def test_resolve_auto_follows_probes(host_input, internal, expect):
     assert capability.resolve_substrate_mode(
-        "auto", host_input=host_input, host_output=False,
+        "auto", host_input=host_input, host_output=True,
         internal=internal) == expect
+    # page_out lands in the host twin through an output sharding: without
+    # host output placement (XLA:CPU) nothing is physical
+    assert capability.resolve_substrate_mode(
+        "auto", host_input=host_input, host_output=False,
+        internal=internal) == "emulated"
 
 
 @pytest.mark.parametrize("host_input,internal", [
     (True, False), (False, True), (False, False),
 ])
 def test_resolve_physical_requires_both_probes(host_input, internal):
+    for host_output in (False, True):
+        with pytest.raises(RuntimeError, match="physical"):
+            capability.resolve_substrate_mode(
+                "physical", host_input=host_input, host_output=host_output,
+                internal=internal)
     with pytest.raises(RuntimeError, match="physical"):
         capability.resolve_substrate_mode(
-            "physical", host_input=host_input, host_output=False,
-            internal=internal)
+            "physical", host_input=True, host_output=False, internal=True)
     assert capability.resolve_substrate_mode(
         "physical", host_input=True, host_output=True,
         internal=True) == "physical"
@@ -91,6 +102,25 @@ def test_resolve_rejects_unknown_mode():
     with pytest.raises(ValueError, match="substrate"):
         capability.resolve_substrate_mode(
             "hbm", host_input=True, host_output=True, internal=True)
+
+
+@pytest.mark.parametrize("platform,surfaces", [("cpu", False),
+                                               ("tpu", True)])
+def test_probe_failure_surfaces_only_on_tpu(monkeypatch, platform,
+                                            surfaces):
+    """A probe that raises reads as "unsupported" on the CPU, where the
+    emulated substrate is the designed fallback; on a TPU it is a fault
+    and must surface instead of quietly demoting the substrate."""
+    def refused():
+        raise RuntimeError("UNIMPLEMENTED: pinned_host placement")
+
+    monkeypatch.setattr(capability.jax, "default_backend", lambda: platform)
+    if surfaces:
+        with pytest.raises(RuntimeError, match="pinned_host"):
+            capability._probe(refused)
+    else:
+        assert capability._probe(refused) is False
+    assert capability._probe(lambda: None) is True
 
 
 def test_substrate_mode_probes_this_backend():
@@ -164,13 +194,12 @@ def test_pool_twin_mirrors_paged_leaves_only():
     cfg = _cfg()
     caches = M.make_paged_decode_caches(cfg, 2, 32, 8,
                                         pool_dtype="int8")
-    twin = init_pool_twin(caches)
-    assert twin
-    for pos, sub in twin.items():
+    pool = paged_pool_leaves(caches)
+    assert pool
+    for pos, sub in pool.items():
         assert set(sub) <= set(PAGED_LEAF_NAMES)
         for name, leaf in sub.items():
-            assert leaf.shape == caches[pos][name].shape
-            assert leaf.dtype == caches[pos][name].dtype
+            assert leaf is caches[pos][name]
 
 
 def test_substrate_disabled_on_ssm_only_stack():
@@ -275,3 +304,73 @@ def test_int8_pool_substrate_measures_quantized_bytes():
     eng8.substrate.sync()
     assert eng8.pager.pool_bytes_used() == pytest.approx(
         eng8.substrate.counters()["placement_bytes"])
+
+
+class _PoolSet:
+    """The two pager reads `TierSubstrate.drain` makes."""
+
+    def __init__(self, ids, n_phys):
+        self.ids = np.asarray(ids, dtype=np.int64)
+        self.ref = np.ones(n_phys, dtype=np.int64)
+
+    def pool_page_ids(self):
+        return self.ids
+
+
+def _random_pool(caches, seed=0):
+    rng = np.random.default_rng(seed)
+    out = {}
+    for pos, c in caches.items():
+        out[pos] = dict(c)
+        for name in PAGED_LEAF_NAMES:
+            if name in c:
+                a = rng.integers(-127, 128, c[name].shape)
+                out[pos][name] = jnp.asarray(a.astype(c[name].dtype))
+    return out
+
+
+@pytest.mark.parametrize("pool_dtype", ["fp", "int8"])
+def test_page_streams_round_trip_pool_pages(pool_dtype):
+    """page_out lands each pool page's bytes in the twin and page_in
+    returns exactly those bytes in the pool's own layout."""
+    caches = _random_pool(M.make_paged_decode_caches(
+        _cfg(), 2, 32, 8, pool_dtype=pool_dtype))
+    sub = TierSubstrate(caches, None, capability.substrate_mode("auto"))
+    sub.drain(_PoolSet([1, 3, 4], sub.n_phys), caches)
+    ids = [4, 1, 3, 3]
+    got = sub._page_in_fn(sub.twin, jnp.asarray(ids, jnp.int32))
+    for pos, leaves in got.items():
+        for name, pages in leaves.items():
+            want = np.asarray(caches[pos][name])[:, ids]
+            np.testing.assert_array_equal(np.asarray(pages), want)
+
+
+@pytest.mark.parametrize("shape,dim,shards", [
+    ((3, 5, 4, 6, 16), 3, 2),     # k/v (nb, P, page, KV, hd), KV over 2
+    ((3, 5, 4, 6, 16), 3, 3),
+    ((3, 5, 6, 2), 2, 2),         # per-page (scale, zero) (nb, P, KV, 2)
+    ((3, 5, 4, 6, 16), None, 1),  # replicated
+])
+def test_twin_row_layout_is_per_shard(shape, dim, shards):
+    """Each shard's twin rows hold exactly that shard's slice of the
+    split dim, and `from_rows` inverts `to_rows`."""
+    from repro.serving.substrate.tier_substrate import RowLayout
+
+    x = jnp.asarray(np.random.default_rng(1).normal(size=shape),
+                    jnp.float32)
+    ids = jnp.asarray([4, 0, 2], jnp.int32)
+    per_shard = int(np.prod(shape[:1] + shape[2:])) // shards
+    lay = RowLayout(shape, dim, ("model",) if dim else None, shards,
+                     -(-per_shard // 1024) * 8)
+    rows = lay.to_rows(x, ids)
+    assert rows.shape == (3, shards, lay.rows, 128)
+    np.testing.assert_array_equal(np.asarray(lay.from_rows(rows)),
+                                  np.asarray(x[:, ids]))
+    if dim is None:
+        return
+    width = shape[dim] // shards
+    for s in range(shards):
+        part = jax.lax.slice_in_dim(x, s * width, (s + 1) * width, axis=dim)
+        one = RowLayout(part.shape, None, None, 1, lay.rows)
+        np.testing.assert_array_equal(np.asarray(rows[:, s:s + 1]),
+                                      np.asarray(one.to_rows(part, ids)))
