@@ -80,6 +80,7 @@ def flash_decode(q, k, v, length, *, scale=None, interpret: bool = False,
                 pltpu.VMEM((KV, rep, 1), jnp.float32),
             ],
         ),
+        name="flash_decode",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

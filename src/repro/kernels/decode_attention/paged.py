@@ -183,6 +183,7 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, lengths, *,
                           kv=KV, sz_mode=sz_mode),
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, D), q.dtype),
         grid_spec=grid_spec,
+        name="paged_flash_decode",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             # MEGACORE partitioning: the batch grid dimension is

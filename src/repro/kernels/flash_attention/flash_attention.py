@@ -117,6 +117,7 @@ def _fwd_pallas(q, k, v, causal, scale, kv_offset, block_q, block_k,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="mha",
         interpret=interpret,
         **kwargs,
     )(q.transpose(head_major), k.transpose(head_major),

@@ -311,6 +311,7 @@ def paged_prefill_flash(q, k_pages, v_pages, block_tables, c0, *,
             out_specs=sp["q"],
             scratch_shapes=_scratch(H, C, D),
         ),
+        name="paged_prefill_flash",
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
     )(block_tables, c0, *operands)
@@ -357,6 +358,7 @@ def paged_prefill_insert_flash(q, k_pages, v_pages, k_new, v_new,
         # inputs count the scalar-prefetch operands: bt(0) c0(1) q(2)
         # k_new(3) v_new(4) k_pages(5) v_pages(6)
         input_output_aliases={5: 1, 6: 2},
+        name="paged_prefill_insert_flash",
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
     )(block_tables, c0, q.transpose(_HEAD_MAJOR), k_new, v_new,
@@ -415,6 +417,7 @@ def paged_prefill_insert_flash_q8(q, k_pages, v_pages, k_sz, v_sz,
         # inputs count the scalar-prefetch operands: bt(0) c0(1) q(2)
         # k8(3) v8(4) kszn(5) vszn(6) kp(7) vp(8) ksz(9) vsz(10)
         input_output_aliases={7: 1, 8: 2, 9: 3, 10: 4},
+        name="paged_prefill_insert_flash_q8",
         interpret=interpret,
         compiler_params=_compiler_params(interpret),
     )(block_tables, c0, q.transpose(_HEAD_MAJOR), k8_new, v8_new,

@@ -47,6 +47,7 @@ def lbench_pallas(a, nflop: int, alpha: float = 0.5, *,
         grid=grid,
         in_specs=[pl.BlockSpec((br, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
+        name="lbench_pallas",
         interpret=interpret,
     )(a2)
     return out.reshape(orig_shape)

@@ -76,6 +76,7 @@ def matmul_w8a8_pallas(a8, b8, sa, sb, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, ki: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        name="matmul_w8a8_pallas",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             # MEGACORE: output tiles are independent -> parallel; only

@@ -59,6 +59,7 @@ def write_pages_pallas(pool, tiles, phys, *, interpret: bool = False):
         grid_spec=grid_spec,
         # inputs count the scalar-prefetch operand: phys(0) tiles(1) pool(2)
         input_output_aliases={2: 0},
+        name="write_pages_pallas",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

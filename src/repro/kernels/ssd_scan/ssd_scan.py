@@ -130,6 +130,7 @@ def _ssd_fwd_pallas(x, dt, A, Bmat, Cmat, D, init_state, chunk, interpret):
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        name="ssd",
         interpret=interpret,
         **kwargs,
     )(A.astype(jnp.float32), D_arr, x.transpose(head_major),

@@ -263,13 +263,13 @@ def build_decode_greedy(cfg: ModelConfig, ctx: ParallelCtx):
     silently wrong token streams). `t` is the per-slot position vector
     (see models.model.decode_step)."""
 
-    def cell(params, token, caches, t):
+    def decode_cell(params, token, caches, t):
         logits, caches = M.decode_step(params, token, caches, t, cfg, ctx)
         finite = jnp.isfinite(logits).all(axis=-1)   # per slot: parked
         # slots carry garbage caches, so the engine masks them out
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), finite, caches
 
-    return cell
+    return decode_cell
 
 
 def build_prefill_greedy(cfg: ModelConfig, ctx: ParallelCtx, bucket: int):
@@ -278,11 +278,11 @@ def build_prefill_greedy(cfg: ModelConfig, ctx: ParallelCtx, bucket: int):
     the frames in the batch) and its greedy first token. Prompts must be
     exactly `bucket` long (see serving.batcher)."""
 
-    def cell(params, batch):
+    def prefill_cell(params, batch):
         caches, logits = M.prefill(params, batch, cfg, ctx, max_seq=bucket)
         return caches, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    return cell
+    return prefill_cell
 
 
 def build_decode_greedy_paged(cfg: ModelConfig, ctx: ParallelCtx,
@@ -292,7 +292,7 @@ def build_decode_greedy_paged(cfg: ModelConfig, ctx: ParallelCtx,
     reads and writes the physical page pool through
     `kernels/decode_attention/paged.py`."""
 
-    def cell(params, token, caches, t, block_table):
+    def decode_cell(params, token, caches, t, block_table):
         logits, caches = M.decode_step(
             params, token, caches, t, cfg, ctx,
             block_table=block_table, page_tokens=page_tokens,
@@ -300,7 +300,7 @@ def build_decode_greedy_paged(cfg: ModelConfig, ctx: ParallelCtx,
         finite = jnp.isfinite(logits).all(axis=-1)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), finite, caches
 
-    return cell
+    return decode_cell
 
 
 def build_decode_verify_paged(cfg: ModelConfig, ctx: ParallelCtx,
@@ -335,7 +335,7 @@ def build_decode_verify_paged(cfg: ModelConfig, ctx: ParallelCtx,
     per-page requantize round trip would make a slot's k rows
     read-modify-write the same tail page concurrently."""
 
-    def cell(params, cand, caches, t, block_table):
+    def verify_cell(params, cand, caches, t, block_table):
         S = cand.shape[0]
         tok_flat = cand.reshape(S * k)
         t_flat = (
@@ -350,7 +350,7 @@ def build_decode_verify_paged(cfg: ModelConfig, ctx: ParallelCtx,
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return greedy.reshape(S, k), finite, caches
 
-    return cell
+    return verify_cell
 
 
 def build_cache_insert():
@@ -358,7 +358,7 @@ def build_cache_insert():
     the global slot caches at a traced slot index. A dynamic-update-slice
     per leaf: leading (stack, batch) dims, then the seq/state extents."""
 
-    def insert(caches, slot_caches, slot):
+    def insert_cell(caches, slot_caches, slot):
         slot = jnp.asarray(slot, jnp.int32)
 
         def ins(big, small):
@@ -369,7 +369,7 @@ def build_cache_insert():
 
         return jax.tree.map(ins, caches, slot_caches)
 
-    return insert
+    return insert_cell
 
 
 def build_decode_draft(cfg: ModelConfig, ctx: ParallelCtx):
@@ -380,11 +380,11 @@ def build_decode_draft(cfg: ModelConfig, ctx: ParallelCtx):
     `build_decode_greedy`; the finite flag is dropped (a non-finite
     draft can only propose tokens the verify cell rejects)."""
 
-    def cell(params, token, caches, t):
+    def draft_cell(params, token, caches, t):
         logits, caches = M.decode_step(params, token, caches, t, cfg, ctx)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
 
-    return cell
+    return draft_cell
 
 
 def build_paged_cache_insert(bucket_total: int, page_tokens: int,
@@ -419,7 +419,7 @@ def build_paged_cache_insert(bucket_total: int, page_tokens: int,
     quantized = pool_dtype == "int8"
     per_token = quantized and sz_granularity == "token"
 
-    def insert(caches, slot_caches, slot, block_table):
+    def insert_cell(caches, slot_caches, slot, block_table):
         slot = jnp.asarray(slot, jnp.int32)
         row = jax.lax.dynamic_index_in_dim(
             block_table, slot, 0, keepdims=False
@@ -467,7 +467,7 @@ def build_paged_cache_insert(bucket_total: int, page_tokens: int,
             out[pos] = oc
         return out
 
-    return insert
+    return insert_cell
 
 
 def build_page_copy():
@@ -483,7 +483,7 @@ def build_page_copy():
     per-slot, never shared). `src`/`dst` are traced scalars: page churn
     replays through one compiled cell."""
 
-    def copy(caches, src, dst):
+    def page_copy_cell(caches, src, dst):
         src = jnp.asarray(src, jnp.int32)
         dst = jnp.asarray(dst, jnp.int32)
 
@@ -502,7 +502,7 @@ def build_page_copy():
             out[pos] = oc
         return out
 
-    return copy
+    return page_copy_cell
 
 
 def build_prefill_chunk(cfg: ModelConfig, ctx: ParallelCtx,
@@ -513,7 +513,7 @@ def build_prefill_chunk(cfg: ModelConfig, ctx: ParallelCtx,
     block table into the pool. Returns the chunk's last-token greedy
     pick (the engine uses it only on the final chunk)."""
 
-    def cell(params, tokens, caches, slot, chunk_idx, block_table):
+    def chunk_cell(params, tokens, caches, slot, chunk_idx, block_table):
         row = jax.lax.dynamic_index_in_dim(
             block_table, jnp.asarray(slot, jnp.int32), 0, keepdims=True
         )                                      # (1, n_pages)
@@ -522,7 +522,7 @@ def build_prefill_chunk(cfg: ModelConfig, ctx: ParallelCtx,
         )
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
 
-    return cell
+    return chunk_cell
 
 
 @dataclasses.dataclass

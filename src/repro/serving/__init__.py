@@ -247,6 +247,13 @@ Architecture (one module per concern):
   queue.py    — `Request` / `RequestQueue` and deterministic arrival
                 scenarios (chat / long-context / bursty /
                 shared-prefix).
+  telemetry.py — host spans (`span` = `jax.profiler.TraceAnnotation`,
+                recorded only under a profiler trace; `SPANS` lists
+                every name the engine, pager and substrate write) and
+                the program clock `now_ns()` on which each request's
+                `Request.wall` stamps (slot taken, first chunk
+                dispatched) are taken; `engine.pump`'s `t_ns` stat
+                ties that clock to the trace's.
   faults.py   — deterministic fault injection: `FaultPlan` (seedable
                 scenario description), `FaultInjector` (per-site
                 Philox streams + one-shot triggers + counters), and

@@ -72,6 +72,7 @@ from repro.serving.kv_pager import LOCAL, KVPager, PagerConfig
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.queue import Request, RequestQueue
 from repro.serving.substrate import TierSubstrate
+from repro.serving.telemetry import RequestWall, now_ns, span
 
 # Minimum per-request greedy-token agreement an int8 pool must keep vs
 # the fp reference: per-page block quantization bounds logit drift, but a
@@ -620,9 +621,16 @@ class ServingEngine:
                 f"request {req.request_id}: prompt+gen exceeds max_seq "
                 f"{self.ecfg.max_seq}"
             )
-        if self.cells.chunk_fn is not None:
-            self._admit_chunked(req, now)
-            return
+        req.wall = RequestWall(admitted_ns=now_ns())
+        with span("engine.admit", request_id=req.request_id):
+            if self.cells.chunk_fn is not None:
+                self._admit_chunked(req, now)
+            else:
+                self._admit_bucket(req, now)
+
+    def _admit_bucket(self, req: Request, now: float) -> None:
+        """Bucketed admission: prefill the whole prompt in its bucket's
+        cell, insert its caches into a slot and emit its first token."""
         bucket = self.batcher.bucket_for(req.prompt_len)
         # shared-prefix match BEFORE any allocation, guard-pinned so the
         # admission's own page allocation cannot reclaim the matched trie
@@ -634,6 +642,7 @@ class ServingEngine:
                 self.pager.pin(hit.all_pages)
         batch = {"tokens": jnp.asarray(req.tokens[None, :]),
                  **self._frontend_extras(req, bucket)}
+        req.wall.first_chunk_ns = now_ns()
         slot_caches, tok = self.cells.prefill_fns[bucket](self.params, batch)
         start = self.npfx + req.prompt_len
         slot = self.batcher.admit(req, start_pos=start)
@@ -726,16 +735,29 @@ class ServingEngine:
         req = slot.request
         C = self.cells.chunk
         end = slot.prefill_pos + C
+        final = end == req.prompt_len
+        with span("engine.prefill_chunk", request_id=req.request_id,
+                  start=slot.prefill_pos, tokens=C, final=final):
+            self._run_chunk(slot, end, final)
+        return True
+
+    def _run_chunk(self, slot, end: int, final: bool) -> None:
+        """Prefill the slot's prompt up to `end` (one chunk); the final
+        chunk emits the first token."""
+        req = slot.request
+        C = self.cells.chunk
         self.pager.extend(slot.index, end)      # own the pages first
         toks = jnp.asarray(req.tokens[None, slot.prefill_pos:end])
+        if req.wall.first_chunk_ns is None:
+            req.wall.first_chunk_ns = now_ns()
         tok, self.caches = self.cells.chunk_fn(
             self.params, toks, self.caches, np.int32(slot.index),
             np.int32(slot.prefill_pos // C),
             self._block_table_dev(),
         )
-        self.virtual_s += self._prefill_dt(C, final=(end == req.prompt_len))
+        self.virtual_s += self._prefill_dt(C, final=final)
         slot.prefill_pos = end
-        if end == req.prompt_len:
+        if final:
             if self.prefix_cache is not None:
                 # chunked prompts are page-multiples: full blocks only
                 self.prefix_cache.insert(
@@ -767,7 +789,6 @@ class ServingEngine:
             else:
                 self.batcher.begin_decode(slot, start_pos=req.prompt_len)
                 self.tokens[slot.index] = first
-        return True
 
     def complete_handoff(self, rec: HandoffRecord) -> None:
         """The transfer copied `rec`'s pages into the decode engine's
@@ -1101,9 +1122,10 @@ class ServingEngine:
         )
 
     def _retire(self, slot) -> Request:
-        req = self.batcher.release(slot)
-        self.pager.release(slot.index)
-        self._draft_fed[slot.index] = 0
+        with span("engine.retire", request_id=slot.request.request_id):
+            req = self.batcher.release(slot)
+            self.pager.release(slot.index)
+            self._draft_fed[slot.index] = 0
         return req
 
     def _block_table_dev(self):
@@ -1123,31 +1145,33 @@ class ServingEngine:
         active = self.batcher.active_mask()
         n_active = int(active.sum())
         t_vec = self.batcher.t_vector()
-        if self.cells.paged:
-            # the write-position page must be live AND private BEFORE the
-            # cell runs: the block table it receives is the layout it
-            # writes through. A shared tail page splits here (COW) and
-            # the copy cell materializes the private duplicate — the
-            # shared page is never mutated.
-            for old, new in self.pager.ensure_tail_pages(active):
-                self.caches = self.cells.copy_fn(
-                    self.caches, np.int32(old), np.int32(new)
+        with span("engine.decode.dispatch"):
+            if self.cells.paged:
+                # the write-position page must be live AND private BEFORE
+                # the cell runs: the block table it receives is the
+                # layout it writes through. A shared tail page splits
+                # here (COW) and the copy cell materializes the private
+                # duplicate — the shared page is never mutated.
+                for old, new in self.pager.ensure_tail_pages(active):
+                    self.caches = self.cells.copy_fn(
+                        self.caches, np.int32(old), np.int32(new)
+                    )
+                next_tok, finite, self.caches = self.cells.decode_fn(
+                    self.params, jnp.asarray(self.tokens), self.caches,
+                    jnp.asarray(t_vec), self._block_table_dev(),
                 )
-            next_tok, finite, self.caches = self.cells.decode_fn(
-                self.params, jnp.asarray(self.tokens), self.caches,
-                jnp.asarray(t_vec), self._block_table_dev(),
-            )
-        else:
-            next_tok, finite, self.caches = self.cells.decode_fn(
-                self.params, jnp.asarray(self.tokens), self.caches,
-                jnp.asarray(t_vec),
-            )
-        next_np = np.asarray(next_tok)
-        if not bool(np.asarray(finite)[active].all()):
-            raise FloatingPointError(
-                f"non-finite decode logits at step {self.steps} "
-                f"(active slots: {n_active})"
-            )
+            else:
+                next_tok, finite, self.caches = self.cells.decode_fn(
+                    self.params, jnp.asarray(self.tokens), self.caches,
+                    jnp.asarray(t_vec),
+                )
+        with span("engine.decode.wait"):
+            next_np = np.asarray(next_tok)
+            if not bool(np.asarray(finite)[active].all()):
+                raise FloatingPointError(
+                    f"non-finite decode logits at step {self.steps} "
+                    f"(active slots: {n_active})"
+                )
 
         traffic = self.pager.step(active)
         t_backoff = 0.0
@@ -1189,18 +1213,22 @@ class ServingEngine:
         self._prev_excess_b = excess_b
         self.admission.observe(n_active, t_pool, dt, t_excess=t_excess)
 
-        self.batcher.advance()
-        for slot in self.batcher.slots:
-            if not slot.active:
-                continue
-            req = slot.request
-            tok = int(next_np[slot.index])
-            self.tokens[slot.index] = tok
-            req.output.append(tok)
-            req.token_times.append(self.virtual_s)
-            if req.done:
-                req.finished = self.virtual_s
-                self._retire(slot)
+        with span("engine.emit") as sp:
+            n_retired = 0
+            self.batcher.advance()
+            for slot in self.batcher.slots:
+                if not slot.active:
+                    continue
+                req = slot.request
+                tok = int(next_np[slot.index])
+                self.tokens[slot.index] = tok
+                req.output.append(tok)
+                req.token_times.append(self.virtual_s)
+                if req.done:
+                    req.finished = self.virtual_s
+                    self._retire(slot)
+                    n_retired += 1
+            sp.set_metadata(n_retired=n_retired)
 
     # ------------------------------------------------------- speculative
     def _history(self, slot) -> np.ndarray:
@@ -1319,23 +1347,26 @@ class ServingEngine:
         cand = np.zeros((self.ecfg.n_slots, k), dtype=np.int32)
         cand[:, 0] = self.tokens
         t_draft = self._propose(cand, active)
-        # all k candidate rows write KV: their pages must be live and
-        # private BEFORE the verify cell runs (rejected tails roll back
-        # through truncate below)
-        for old, new in self.pager.ensure_tail_pages(active, lookahead=k):
-            self.caches = self.cells.copy_fn(
-                self.caches, np.int32(old), np.int32(new)
+        with span("engine.decode.dispatch"):
+            # all k candidate rows write KV: their pages must be live and
+            # private BEFORE the verify cell runs (rejected tails roll
+            # back through truncate below)
+            for old, new in self.pager.ensure_tail_pages(active,
+                                                         lookahead=k):
+                self.caches = self.cells.copy_fn(
+                    self.caches, np.int32(old), np.int32(new)
+                )
+            greedy, finite, self.caches = self.cells.verify_fn(
+                self.params, jnp.asarray(cand), self.caches,
+                jnp.asarray(t_vec), self._block_table_dev(),
             )
-        greedy, finite, self.caches = self.cells.verify_fn(
-            self.params, jnp.asarray(cand), self.caches,
-            jnp.asarray(t_vec), self._block_table_dev(),
-        )
-        greedy_np = np.asarray(greedy)
-        if not bool(np.asarray(finite)[active].all()):
-            raise FloatingPointError(
-                f"non-finite verify logits at step {self.steps} "
-                f"(active slots: {n_active})"
-            )
+        with span("engine.decode.wait"):
+            greedy_np = np.asarray(greedy)
+            if not bool(np.asarray(finite)[active].all()):
+                raise FloatingPointError(
+                    f"non-finite verify logits at step {self.steps} "
+                    f"(active slots: {n_active})"
+                )
 
         # greedy acceptance per slot, capped by the request's remaining
         # decode budget (the verify row may overshoot max_new_tokens)
@@ -1387,25 +1418,30 @@ class ServingEngine:
         self._prev_excess_b = excess_b
         self.admission.observe(n_active, t_pool, dt, t_excess=t_excess)
 
-        self.batcher.advance(counts)
-        for slot in self.batcher.slots:
-            if not slot.active:
-                continue
-            req = slot.request
-            emit = emits[slot.index]
-            self.tokens[slot.index] = emit[-1]
-            for tok in emit:
-                req.output.append(int(tok))
-                req.token_times.append(self.virtual_s)
-            if req.done:
-                req.finished = self.virtual_s
-                self._retire(slot)     # releases every page incl. lookahead
-            else:
-                # partial acceptance: free the lookahead pages past the
-                # committed frontier so pool footprint tracks ACCEPTED
-                # tokens (the rejected KV itself is dead weight the
-                # kernels mask and the next verify overwrites)
-                self.pager.truncate(slot.index)
+        with span("engine.emit") as sp:
+            n_retired = 0
+            self.batcher.advance(counts)
+            for slot in self.batcher.slots:
+                if not slot.active:
+                    continue
+                req = slot.request
+                emit = emits[slot.index]
+                self.tokens[slot.index] = emit[-1]
+                for tok in emit:
+                    req.output.append(int(tok))
+                    req.token_times.append(self.virtual_s)
+                if req.done:
+                    req.finished = self.virtual_s
+                    self._retire(slot)  # every page incl. lookahead
+                    n_retired += 1
+                else:
+                    # partial acceptance: free the lookahead pages past
+                    # the committed frontier so pool footprint tracks
+                    # ACCEPTED tokens (the rejected KV itself is dead
+                    # weight the kernels mask and the next verify
+                    # overwrites)
+                    self.pager.truncate(slot.index)
+            sp.set_metadata(n_retired=n_retired)
 
     # ----------------------------------------------- admission <-> sched
     def measured_profile(self) -> itf.InterferenceProfile:
@@ -1482,6 +1518,10 @@ class ServingEngine:
         possible — the caller owns clock advancement) | "dead" /
         "stalled" (fault injection: the engine cannot make progress;
         the fleet router's watchdog recovers it)."""
+        with span("engine.pump", step=self.steps, t_ns=now_ns()):
+            return self._pump(q)
+
+    def _pump(self, q: RequestQueue) -> str:
         act = self._fault_tick()
         if act is not None:
             return act
@@ -1497,15 +1537,17 @@ class ServingEngine:
             self._admit(q.pop(self.virtual_s), self.virtual_s)
             admitted = True
         chunk_ran = self._prefill_tick()
-        if self.batcher.n_active == 0:
+        n_active = self.batcher.n_active
+        if n_active == 0:
             if chunk_ran:
                 return "chunk"
             return "admit" if admitted or restored else "idle"
-        self._max_conc = max(self._max_conc, self.batcher.n_active)
-        if self.cells.verify_fn is not None:
-            self._step_speculative()
-        else:
-            self._step_decode()
+        self._max_conc = max(self._max_conc, n_active)
+        with span("engine.decode", step=self.steps, n_active=n_active):
+            if self.cells.verify_fn is not None:
+                self._step_speculative()
+            else:
+                self._step_decode()
         return "decode"
 
     def begin_capture(self) -> dict:

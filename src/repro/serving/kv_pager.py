@@ -106,6 +106,7 @@ from repro.core import placement as plc
 from repro.core import tiers as tr
 from repro.core.access import DECODE_COLD_TOUCH, DECODE_HOT_WINDOW, \
     TensorAccess
+from repro.serving.telemetry import span
 
 LOCAL, POOL = 0, 1
 
@@ -724,7 +725,45 @@ class KVPager:
         token by it. (Rejected candidate rows also wrote KV, but those
         are overwritten in place before ever being read — sub-token
         noise against the per-step page sweep, excluded by the model.)"""
-        active = np.asarray(active, dtype=bool)
+        with span("pager.step", step=self._steps):
+            return self._step(np.asarray(active, dtype=bool), tokens)
+
+    def _step(self, active: np.ndarray,
+              tokens: Optional[np.ndarray]) -> StepTraffic:
+        with span("pager.touches"):
+            local_r, pool_r, demand_b, staged_b = self._read_traffic(active)
+        with span("pager.writes"):
+            wr_local, wr_pool = self._write_tails(active, tokens)
+        cow_local, cow_pool = self._cow_local_pending, self._cow_pool_pending
+        self._cow_local_pending = self._cow_pool_pending = 0.0
+        local_b = (local_r + wr_local + cow_local
+                   + self.resident_bytes * active.sum())
+        pool_b = pool_r + wr_pool + demand_b + staged_b + cow_pool
+
+        self._steps += 1
+        if (self.cfg.policy == "hotness"
+                and self._steps % self.cfg.rebalance_every == 0):
+            with span("pager.rebalance") as sp:
+                sp.set_metadata(pages=self.rebalance())
+
+        self.total_local_bytes += local_b
+        self.total_pool_bytes += pool_b
+        if self._predictor is None:
+            # legacy overlap assumption: all pool traffic prefetchable
+            demand, staged = 0.0, pool_b
+        else:
+            # COW pool copies serialize like demand page-ins: the split
+            # must land before the write the decode cell is about to do
+            demand = demand_b + wr_pool + cow_pool
+            staged = staged_b
+        self.total_demand_pool_bytes += demand
+        self.total_prefetch_pool_bytes += staged
+        return StepTraffic(local_b, pool_b, demand, staged)
+
+    def _read_traffic(self, active: np.ndarray
+                      ) -> Tuple[float, float, float, float]:
+        """The step's page reads: (local, pool, demand page-in, staged
+        prefetch) bytes."""
         touches = None
         if self.recorder is not None or self._predictor is not None:
             touches = self._discrete_touches(active)
@@ -777,7 +816,12 @@ class KVPager:
                     self._staged.add((s, p))
                     self.prefetch_issued += 1
                     staged_b += self.page_bytes
+        return local_r, pool_r, demand_b, staged_b
 
+    def _write_tails(self, active: np.ndarray,
+                     tokens: Optional[np.ndarray]) -> Tuple[float, float]:
+        """Advance each active slot by its tokens, allocating or splitting
+        its tail page as needed: (local, pool) bytes written."""
         # tokens[s] (default 1) tokens of KV written at the tail of each
         # active slot — each write page must be private, so a shared tail
         # page splits first (COW; never mutate a page with ref > 1)
@@ -798,33 +842,10 @@ class KVPager:
                 else:
                     wr_local += self.bytes_per_token
                 self.lengths[s] += 1
-        cow_local, cow_pool = self._cow_local_pending, self._cow_pool_pending
-        self._cow_local_pending = self._cow_pool_pending = 0.0
-        local_b = (local_r + wr_local + cow_local
-                   + self.resident_bytes * active.sum())
-        pool_b = pool_r + wr_pool + demand_b + staged_b + cow_pool
-
-        self._steps += 1
-        if (self.cfg.policy == "hotness"
-                and self._steps % self.cfg.rebalance_every == 0):
-            self.rebalance()
-
-        self.total_local_bytes += local_b
-        self.total_pool_bytes += pool_b
-        if self._predictor is None:
-            # legacy overlap assumption: all pool traffic prefetchable
-            demand, staged = 0.0, pool_b
-        else:
-            # COW pool copies serialize like demand page-ins: the split
-            # must land before the write the decode cell is about to do
-            demand = demand_b + wr_pool + cow_pool
-            staged = staged_b
-        self.total_demand_pool_bytes += demand
-        self.total_prefetch_pool_bytes += staged
-        return StepTraffic(local_b, pool_b, demand, staged)
+        return wr_local, wr_pool
 
     # --------------------------------------------------------- placement
-    def rebalance(self) -> None:
+    def rebalance(self) -> int:
         """Re-place live pages with the paper's placement engine: build a
         PHYSICAL-page-grain access profile and run the `hotness` policy
         against the local budget — the exact analogue of
@@ -832,12 +853,13 @@ class KVPager:
         at tensor grain. A shared page's weight is the SUM of its
         sharers' touch weights (ten sharers of a prefix page make it ten
         times hotter than any single copy — dedup concentrates heat);
-        trie-only pages carry no slot weight and drift poolward first."""
+        trie-only pages carry no slot weight and drift poolward first.
+        Returns the number of pages placed (its cost grows with it)."""
         owned = np.nonzero(self.ref > 0)[0]
         n_owned = len(owned)
         if (n_owned == 0 or not np.isfinite(self.budget)
                 or self.page_bytes <= 0):
-            return  # nothing paged (e.g. SSM-only archs: no self-attn KV)
+            return 0  # nothing paged (e.g. SSM-only archs: no self-attn KV)
         w_sp = self._page_weights()
         wg = np.zeros(self.n_phys)
         np.add.at(wg, self.phys[self.valid], w_sp[self.valid])
@@ -873,6 +895,7 @@ class KVPager:
                 if self.valid[s, p]
                 and self.tier_phys[self.phys[s, p]] == POOL
             }
+        return n_owned
 
     # ----------------------------------------------------------- metrics
     def remote_share(self) -> float:

@@ -46,6 +46,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.serving.telemetry import RequestWall
+
 
 @dataclasses.dataclass
 class Request:
@@ -65,6 +67,9 @@ class Request:
     finished: float = float("nan")
     output: List[int] = dataclasses.field(default_factory=list)
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # program-clock stamps (serving.telemetry); virtual times above
+    wall: RequestWall = dataclasses.field(default_factory=RequestWall,
+                                          compare=False, repr=False)
 
     @property
     def prompt_len(self) -> int:

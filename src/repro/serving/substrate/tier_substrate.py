@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 from repro.models import blocks
 from repro.runtime import sharding as shd
 from repro.serving.substrate.ledger import SubstrateLedger
+from repro.serving.telemetry import span
 
 _LANES = 128
 
@@ -290,30 +291,33 @@ class TierSubstrate:
         counts of this drain."""
         if not self.enabled:
             return {}
-        target = set(pager.pool_page_ids().tolist())
-        outs = sorted(target - self._resident)
-        gone = self._resident - target
-        promoted = sorted(p for p in gone if pager.ref[p] > 0)
-        freed = sorted(p for p in gone if pager.ref[p] <= 0)
-        if freed:
-            self.ledger.record("drop", len(freed), step=step)
-        if promoted:
-            self._attempt_transfer("page_in", len(promoted), step)
-            # gather BEFORE page_out donates (and thus invalidates) the
-            # current twin buffer
-            got = self._page_in_fn(self.twin, self._pad_ids(promoted))
-            self.ledger.record("page_in", len(promoted), step=step,
-                               payload=tuple(jax.tree.leaves(got)))
-        if outs:
-            self._attempt_transfer("page_out", len(outs), step)
-            self.twin = self._page_out_fn(
-                self.twin, _pool_leaves(caches, self.twin),
-                self._pad_ids(outs))
-            self.ledger.record("page_out", len(outs), step=step,
-                               payload=tuple(jax.tree.leaves(self.twin)))
-        self._resident = target
-        return {"page_out": len(outs), "page_in": len(promoted),
-                "drop": len(freed)}
+        with span("substrate.drain") as sp:
+            target = set(pager.pool_page_ids().tolist())
+            outs = sorted(target - self._resident)
+            gone = self._resident - target
+            promoted = sorted(p for p in gone if pager.ref[p] > 0)
+            freed = sorted(p for p in gone if pager.ref[p] <= 0)
+            sp.set_metadata(n_out=len(outs), n_in=len(promoted),
+                            n_drop=len(freed))
+            if freed:
+                self.ledger.record("drop", len(freed), step=step)
+            if promoted:
+                self._attempt_transfer("page_in", len(promoted), step)
+                # gather BEFORE page_out donates (and thus invalidates) the
+                # current twin buffer
+                got = self._page_in_fn(self.twin, self._pad_ids(promoted))
+                self.ledger.record("page_in", len(promoted), step=step,
+                                   payload=tuple(jax.tree.leaves(got)))
+            if outs:
+                self._attempt_transfer("page_out", len(outs), step)
+                self.twin = self._page_out_fn(
+                    self.twin, _pool_leaves(caches, self.twin),
+                    self._pad_ids(outs))
+                self.ledger.record("page_out", len(outs), step=step,
+                                   payload=tuple(jax.tree.leaves(self.twin)))
+            self._resident = target
+            return {"page_out": len(outs), "page_in": len(promoted),
+                    "drop": len(freed)}
 
     def record_handoff(self, n_pages: int, *, step: int = 0) -> None:
         """Account a fleet prefill->decode handoff copy (roles.py runs
