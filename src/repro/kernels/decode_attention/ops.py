@@ -60,9 +60,16 @@ def paged_decode_mha(
 ):
     """q (B,H,D) vs a PAGED cache: k/v (P_phys, page, KV, D) physical page
     pool + (B, n_logical) block tables (`KVPager.block_table` layout) with
-    per-sequence valid `lengths`. Block-table entries past the valid
-    length are clamped to physical page 0 so the gather stays in bounds
-    on every backend; the length mask keeps them out of the math.
+    per-sequence valid `lengths`.
+
+    A length past the table (> n_logical * page) marks a PARKED row: the
+    engine parks every slot that is not decoding at n_logical * page, so
+    its `t + 1` lands there, and the cache insert drops writes at such
+    positions. Such a row has no live page and returns zeros, on every
+    backend. Block-table entries at or past a row's length are never
+    read by the kernel; the reference clamps them to physical page 0 so
+    its gather stays in bounds, and the length mask keeps them out of
+    the math.
 
     `k_sz`/`v_sz` (P_phys, KV, 2) float32 switch the pool to int8 block
     quantization (`repro.kernels.quant`): the payload is int8 and the
@@ -73,9 +80,11 @@ def paged_decode_mha(
     lengths = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32), (q.shape[0],)
     )
-    block_tables = clamp_dead_entries(block_tables, n_pages, page, lengths)
+    lengths = jnp.where(lengths > n_pages * page, 0, lengths)
     kind, interpret = select_impl(impl)
     if kind == "reference":
+        block_tables = clamp_dead_entries(block_tables, n_pages, page,
+                                          lengths)
         return ref.paged_decode_mha(q, k_pages, v_pages, block_tables,
                                     lengths, k_sz=k_sz, v_sz=v_sz,
                                     scale=scale)

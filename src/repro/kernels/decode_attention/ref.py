@@ -83,10 +83,11 @@ def decode_mha(
     mask = jnp.arange(S)[None, :] < length[:, None]      # (B,S)
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     m = logits.max(axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)     # length 0: all masked
     p = jnp.exp(logits - m)
     l = p.sum(axis=-1, keepdims=True)
     out = jnp.einsum(
         "bgrs,bsgd->bgrd", p.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
-    ) / l[..., 0:1]
+    ) / jnp.where(l > 0, l, 1.0)[..., 0:1]     # ... returns zeros
     return out.reshape(B, H, D).astype(q.dtype)

@@ -1128,6 +1128,19 @@ class ServingEngine:
             self._draft_fed[slot.index] = 0
         return req
 
+    def _kv_pages(self) -> int:
+        """Pages the paged decode kernel walks in this step: ceil(length /
+        page) summed over its rows that are not parked (a row's length
+        is t + 1, or t + j + 1 for verify row j; parked rows land past
+        the table and walk none)."""
+        page = self.cells.page_tokens
+        rows = (self.cells.spec_k if self.cells.verify_fn is not None
+                else 1)
+        lengths = (self.batcher.t_vector()[:, None] + 1
+                   + np.arange(rows)[None, :])
+        lengths = lengths[lengths <= self.cells.n_pages * page]
+        return int(((lengths + page - 1) // page).sum())
+
     def _block_table_dev(self):
         bt = self.pager.block_table()
         if bt is not self._bt_host:
@@ -1543,7 +1556,10 @@ class ServingEngine:
                 return "chunk"
             return "admit" if admitted or restored else "idle"
         self._max_conc = max(self._max_conc, n_active)
-        with span("engine.decode", step=self.steps, n_active=n_active):
+        stats = {"step": self.steps, "n_active": n_active}
+        if self.cells.paged:
+            stats["kv_pages"] = self._kv_pages()
+        with span("engine.decode", **stats):
             if self.cells.verify_fn is not None:
                 self._step_speculative()
             else:

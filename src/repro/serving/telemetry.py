@@ -43,7 +43,8 @@ SPANS = {
                             "first-token read on the final chunk; stats "
                             "request_id, start, tokens, final",
     "engine.decode": "one decode (or speculative verify) step over the "
-                     "slot batch; stats step, n_active",
+                     "slot batch; stats step, n_active, kv_pages (paged "
+                     "cells: pages the decode kernel walks)",
     "engine.decode.dispatch": "tail pages and COW copies, block-table "
                               "upload, the decode cell's call",
     "engine.decode.wait": "the host blocked on the device for the step's "

@@ -88,6 +88,23 @@ def test_paged_decode_compiles(one_chip, sz):
     _compile(one_chip, fn, *shapes)
 
 
+def test_paged_decode_compiles_at_benchmark_shapes(one_chip):
+    """The benchmark's smollm_360m pool: 64 slots x 128 logical pages of
+    16 tokens, 8,192 physical pages, int8 with per-page scales."""
+    slots, n_log = 64, 128
+    def fn(q, kp, vp, bt, ln, ksz, vsz):
+        return dops.paged_decode_mha(q, kp, vp, bt, ln, k_sz=ksz, v_sz=vsz,
+                                     impl="pallas")
+    _compile(one_chip, fn,
+             ((slots, H, HD), jnp.bfloat16),
+             ((slots * n_log, PAGE, KV, HD), jnp.int8),
+             ((slots * n_log, PAGE, KV, HD), jnp.int8),
+             ((slots, n_log), jnp.int32),
+             ((slots,), jnp.int32),
+             ((slots * n_log, KV, 2), jnp.float32),
+             ((slots * n_log, KV, 2), jnp.float32))
+
+
 def test_paged_decode_compiles_mistral_nemo_tp_shard(one_chip):
     """One of four tp shards of mistral_nemo_12b: 32/4 query heads over
     8/4 KV heads, head_dim 128, int8 pool with per-page scales."""

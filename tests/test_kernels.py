@@ -165,6 +165,26 @@ def _paged_layout(k, v, page, seed=0, extra_phys=3):
     return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt)
 
 
+def _quant_pool(kp, vp, sz):
+    """The pool as the kernel takes it: fp pages (sz "none"), or int8
+    pages with per-page / per-token (scale, zero) leaves."""
+    if sz == "none":
+        return kp, vp, {}
+    from repro.kernels import quant
+
+    quantize = quant.quantize_pages if sz == "page" else quant.quantize_tokens
+    k8, ksz = quantize(jnp.asarray(kp, jnp.float32))
+    v8, vsz = quantize(jnp.asarray(vp, jnp.float32))
+    return k8, v8, {"k_sz": ksz, "v_sz": vsz}
+
+
+def _paged_ref(q, kp, vp, bt, lengths, sz_kw):
+    """The oracle over the same (possibly quantized) pool."""
+    return dops.paged_decode_mha(q, kp, vp, bt, lengths, impl="reference",
+                                 **sz_kw)
+
+
+@pytest.mark.parametrize("sz", ["none", "page", "token"])
 @pytest.mark.parametrize("page", [16, 64, 128])
 @pytest.mark.parametrize(
     "B,S,H,KV,D,dtype",
@@ -174,10 +194,11 @@ def _paged_layout(k, v, page, seed=0, extra_phys=3):
         (2, 512, 8, 2, 64, jnp.bfloat16),
     ],
 )
-def test_paged_decode_matches_dense_ref(page, B, S, H, KV, D, dtype):
+def test_paged_decode_matches_dense_ref(sz, page, B, S, H, KV, D, dtype):
     """Acceptance: the paged kernel == the dense oracle token-for-token
     across page sizes {16, 64, 128} with scattered physical pages and
-    ragged lengths."""
+    ragged lengths; with an int8 pool (per-page or per-token scales) ==
+    the dequant-gather oracle over the same pool."""
     ks = jax.random.split(jax.random.PRNGKey(S + D + page), 3)
     q = _rand(ks[0], (B, H, D), dtype)
     k = _rand(ks[1], (B, S, KV, D), dtype)
@@ -185,13 +206,182 @@ def test_paged_decode_matches_dense_ref(page, B, S, H, KV, D, dtype):
     lengths = jnp.array([(S // 2 + 17 * i) % S + 1 for i in range(B)],
                         jnp.int32)
     kp, vp, bt = _paged_layout(k, v, page, seed=page)
-    r = dref.decode_mha(q, k, v, lengths)
-    p = paged_flash_decode(q, kp, vp, bt, lengths, interpret=True)
+    kp, vp, sz_kw = _quant_pool(kp, vp, sz)
+    if sz == "none":
+        r = dref.decode_mha(q, k, v, lengths)
+    else:
+        r = _paged_ref(q, kp, vp, bt, lengths, sz_kw)
+    p = paged_flash_decode(q, kp, vp, bt, lengths, interpret=True, **sz_kw)
     tol = TOL[dtype]
     np.testing.assert_allclose(
         np.asarray(p, np.float32), np.asarray(r, np.float32),
         rtol=tol, atol=tol,
     )
+
+
+def _block_tokens(page, KV, D, dtype, sz, n_pages):
+    from repro.kernels.decode_attention import paged as pg
+
+    return page * pg.block_pages(page, KV, D, dtype, sz, n_pages)
+
+
+@pytest.mark.parametrize("sz", ["none", "page", "token"])
+def test_paged_decode_ragged_lengths_at_page_and_block_edges(sz):
+    """Lengths of 1, a page, a page + 1, a block (the kernel's pages a
+    grid step), a block + 1 and the whole table, in one call."""
+    S, H, KV, D, page = 1024, 4, 2, 64, 16
+    bk = _block_tokens(page, KV, D, jnp.int8 if sz != "none"
+                       else jnp.float32, sz, S // page)
+    assert page < bk < S
+    lengths = jnp.array([1, page, page + 1, bk, bk + 1, S], jnp.int32)
+    B = lengths.shape[0]
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = _rand(ks[0], (B, H, D), jnp.float32)
+    k = _rand(ks[1], (B, S, KV, D), jnp.float32)
+    v = _rand(ks[2], (B, S, KV, D), jnp.float32)
+    kp, vp, bt = _paged_layout(k, v, page, seed=4)
+    kp, vp, sz_kw = _quant_pool(kp, vp, sz)
+    r = _paged_ref(q, kp, vp, bt, lengths, sz_kw)
+    p = dops.paged_decode_mha(q, kp, vp, bt, lengths, impl="interpret",
+                              **sz_kw)
+    np.testing.assert_allclose(np.asarray(p), np.asarray(r), rtol=2e-5,
+                               atol=2e-5)
+    if sz == "none":
+        np.testing.assert_allclose(
+            np.asarray(p), np.asarray(dref.decode_mha(q, k, v, lengths)),
+            rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["reference", "interpret"])
+@pytest.mark.parametrize("sz", ["none", "page"])
+def test_paged_decode_parked_and_empty_rows_are_zero(impl, sz):
+    """A parked row (length past the table: the engine parks idle slots
+    at n_pages * page, so t + 1 lands one past it) and a row of length 0
+    have no live page: zeros, finite, on the kernel and the oracle; the
+    live rows of the same call are untouched."""
+    B, S, H, KV, D, page = 4, 256, 4, 2, 64, 16
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = _rand(ks[0], (B, H, D), jnp.float32)
+    k = _rand(ks[1], (B, S, KV, D), jnp.float32)
+    v = _rand(ks[2], (B, S, KV, D), jnp.float32)
+    kp, vp, bt = _paged_layout(k, v, page, seed=2)
+    kp, vp, sz_kw = _quant_pool(kp, vp, sz)
+    lengths = jnp.array([S + 1, 40, 0, S], jnp.int32)
+    out = np.asarray(dops.paged_decode_mha(q, kp, vp, bt, lengths,
+                                           impl=impl, **sz_kw))
+    assert np.isfinite(out).all()
+    assert (out[[0, 2]] == 0).all()
+    live = _paged_ref(q[1::2], kp, vp, bt[1::2], lengths[1::2], sz_kw)
+    np.testing.assert_allclose(out[1::2], np.asarray(live), rtol=2e-5,
+                               atol=2e-5)
+    none = dops.paged_decode_mha(q, kp, vp, bt, jnp.array([0, S + 1] * 2),
+                                 impl=impl, **sz_kw)
+    assert (np.asarray(none) == 0).all()        # no live row at all
+
+
+@pytest.mark.parametrize("sz", ["page", "token"])
+def test_paged_decode_repeated_table_rows(sz):
+    """The verify cell's call: B = slots x k rows, each slot's block-table
+    row repeated k times, row j of a slot at length t + j + 1 (one of
+    them past the table: parked)."""
+    n_slots, k_rep, S, H, KV, D, page = 2, 3, 256, 4, 2, 64, 16
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    kd = _rand(ks[1], (n_slots, S, KV, D), jnp.float32)
+    vd = _rand(ks[2], (n_slots, S, KV, D), jnp.float32)
+    kp, vp, bt = _paged_layout(kd, vd, page, seed=3)
+    kp, vp, sz_kw = _quant_pool(kp, vp, sz)
+    t = np.array([page * 5 - 2, S - 2])
+    lengths = jnp.asarray((t[:, None] + 1 + np.arange(k_rep)).reshape(-1),
+                          jnp.int32)
+    bt_rows = jnp.repeat(bt, k_rep, axis=0)
+    B = n_slots * k_rep
+    assert bt_rows.shape[0] == B != bt.shape[0]
+    q = _rand(ks[0], (B, H, D), jnp.float32)
+    r = dops.paged_decode_mha(q, kp, vp, bt_rows, lengths,
+                              impl="reference", **sz_kw)
+    p = dops.paged_decode_mha(q, kp, vp, bt_rows, lengths,
+                              impl="interpret", **sz_kw)
+    np.testing.assert_allclose(np.asarray(p), np.asarray(r), rtol=2e-5,
+                               atol=2e-5)
+    assert (np.asarray(p)[-1] == 0).all()        # S - 2 + 2 + 1 > S
+
+
+@pytest.mark.parametrize("sz", ["none", "page"])
+def test_paged_decode_ignores_garbage_past_the_length(sz):
+    """Everything past a row's length — the tail rows of its last page,
+    the block-table entries after it and the physical pages they name —
+    filled with large finite garbage leaves the output as it was; so does
+    NaN in a physical page that no live entry names."""
+    B, S, H, KV, D, page = 3, 512, 4, 2, 64, 16
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = _rand(ks[0], (B, H, D), jnp.float32)
+    k = _rand(ks[1], (B, S, KV, D), jnp.float32)
+    v = _rand(ks[2], (B, S, KV, D), jnp.float32)
+    lengths = np.array([37, 300, 16], np.int32)
+    kp, vp, bt = _paged_layout(k, v, page, seed=5, extra_phys=4)
+    kp, vp, bt = np.asarray(kp).copy(), np.asarray(vp).copy(), np.asarray(bt)
+    n_log = S // page
+    live = np.arange(n_log)[None, :] * page < lengths[:, None]
+    spare = np.setdiff1d(np.arange(kp.shape[0]), bt[live])
+    # reference output: dead table entries at a spare zero page, the
+    # pages nothing live names zeroed, the tail rows of last pages zeroed
+    clean_k, clean_v = kp.copy(), vp.copy()
+    clean_k[spare] = 0.0
+    clean_v[spare] = 0.0
+    for b in range(B):
+        last, off = divmod(int(lengths[b]), page)
+        if off:
+            clean_k[bt[b, last], off:] = 0.0
+            clean_v[bt[b, last], off:] = 0.0
+    clean_bt = np.where(live, bt, spare[0])
+    dirty_k, dirty_v = clean_k.copy(), clean_v.copy()
+    dirty_k[spare] = 3e4
+    dirty_v[spare] = -3e4
+    for b in range(B):
+        last, off = divmod(int(lengths[b]), page)
+        if off:
+            dirty_k[bt[b, last], off:] = 2e4
+            dirty_v[bt[b, last], off:] = -2e4
+    dirty_bt = bt.copy()                 # dead entries name dirty pages
+    dead_targets = spare[1:]
+    dirty_bt[~live] = np.resize(dead_targets, (~live).sum())
+
+    def run(kq, vq, table):
+        kq, vq, sz_kw = _quant_pool(jnp.asarray(kq), jnp.asarray(vq), sz)
+        return np.asarray(dops.paged_decode_mha(
+            q, kq, vq, jnp.asarray(table), jnp.asarray(lengths),
+            impl="interpret", **sz_kw))
+
+    want = run(clean_k, clean_v, clean_bt)
+    if sz == "none":
+        np.testing.assert_allclose(
+            want, np.asarray(dref.decode_mha(q, k, v, lengths)),
+            rtol=2e-5, atol=2e-5)
+    # with int8 pages the garbage in a live page's tail moves that page's
+    # (scale, zero); the comparison is then with the oracle on that pool
+    got = run(dirty_k, dirty_v, dirty_bt)
+    if sz == "none":
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    else:
+        kq, vq, sz_kw = _quant_pool(jnp.asarray(dirty_k),
+                                    jnp.asarray(dirty_v), sz)
+        np.testing.assert_allclose(
+            got, np.asarray(_paged_ref(q, kq, vq, jnp.asarray(dirty_bt),
+                                       jnp.asarray(lengths), sz_kw)),
+            rtol=2e-5, atol=2e-5)
+    nan_k, nan_v = dirty_k.copy(), dirty_v.copy()
+    nan_k[dead_targets[0]] = np.nan
+    nan_v[dead_targets[0]] = np.nan
+    if sz == "none":
+        got = run(nan_k, nan_v, dirty_bt)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    else:
+        # NaN pages quantize to NaN scales: still never read
+        got = run(nan_k, nan_v, dirty_bt)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, run(dirty_k, dirty_v, dirty_bt),
+                                   rtol=0, atol=0)
 
 
 def test_paged_ops_clamps_dead_table_entries():

@@ -169,3 +169,31 @@ def test_greedy_tokens_same_with_profiler_on_and_off(traced):
     reqs, _ = traced
     _, plain = _serve()
     assert [r.output for r in plain] == [r.output for r in reqs]
+
+
+def test_decode_span_counts_the_kernels_pages(traced):
+    """`engine.decode`'s `kv_pages`: the pages the decode kernel walks,
+    at least a page for each decoding row and none for a parked one, so
+    never more than the decoding rows' whole tables."""
+    _, spans = traced
+    per_row = ENGINE["max_seq"] // ENGINE["page_tokens"]
+    decodes = _named(spans, "engine.decode")
+    assert decodes
+    for sp in decodes:
+        st = sp[3]
+        assert st["n_active"] <= st["kv_pages"] <= st["n_active"] * per_row
+    assert any(sp[3]["n_active"] < ENGINE["n_slots"] for sp in decodes)
+
+
+def test_kv_pages_skips_parked_rows():
+    cfg = dataclasses.replace(configs.reduced("smollm_360m"),
+                              dtype="float32")
+    eng = ServingEngine.build(cfg, CTX, EngineConfig(**ENGINE))
+    page = ENGINE["page_tokens"]
+    assert eng._kv_pages() == 0                     # every slot parked
+    slots = eng.batcher.slots
+    for slot, t in zip(slots, (0, page - 1, 2 * page + 3)):
+        slot.request, slot.phase, slot.t = _requests(8, n=1)[0], "decode", t
+    assert eng._kv_pages() == 1 + 1 + 3
+    slots[1].phase = "prefill"                      # parked again
+    assert eng._kv_pages() == 1 + 3
