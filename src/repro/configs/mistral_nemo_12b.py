@@ -20,14 +20,18 @@ CONFIG = ModelConfig(
 
 
 def reduced() -> ModelConfig:
+    """What sets the block apart, at toy widths: the query width (8 x 16)
+    below the hidden size, GQA 4:1 with a KV head a shard at tp=2, an
+    untied head and rope_theta 1e6."""
     return ModelConfig(
         name="mistral-nemo-reduced",
         family="dense",
         num_layers=2,
-        d_model=64,
-        num_heads=4,
+        d_model=160,
+        num_heads=8,
         num_kv_heads=2,
         head_dim=16,
-        d_ff=128,
+        d_ff=448,
         vocab_size=128,
+        rope_theta=1_000_000.0,
     )

@@ -466,7 +466,15 @@ def analyze_hlo(hlo: str, default_group: int = 1) -> HloCostModel:
         memo[comp_name] = result
         return result
 
-    # entry computation = the one not called by anyone
+    entry = _entry(comps)
+    if entry is None:
+        return HloCostModel(0, 0, 0, 0, {}, ["no entry computation found"])
+    flops, raw, fused, wire, coll = cost_of(entry)
+    return HloCostModel(flops, fused, raw, wire, coll, warnings)
+
+
+def _entry(comps: dict):
+    """The entry computation: the largest one no op calls."""
     called = set()
     for c in comps.values():
         for op in c.ops:
@@ -480,11 +488,36 @@ def analyze_hlo(hlo: str, default_group: int = 1) -> HloCostModel:
                 for nm in _NAME_RE.finditer(m.group(1)):
                     called.add(nm.group(1))
     entries = [c for c in comps if c not in called]
-    entry = None
-    for c in entries:
-        if entry is None or len(comps[c].ops) > len(comps[entry].ops):
-            entry = c
-    if entry is None:
-        return HloCostModel(0, 0, 0, 0, {}, ["no entry computation found"])
-    flops, raw, fused, wire, coll = cost_of(entry)
-    return HloCostModel(flops, fused, raw, wire, coll, warnings)
+    return max(entries, key=lambda c: len(comps[c].ops), default=None)
+
+
+def collective_count(hlo: str) -> int:
+    """Cross-device collective ops one execution of the program runs:
+    each while body's count times its trip count, an async pair
+    (`-start` ... `-done`) once."""
+    comps = _parse_computations(hlo)
+    memo: dict = {}
+
+    def count(name: str) -> int:
+        if name in memo or name not in comps:
+            return memo.get(name, 0)
+        n = 0
+        for op in comps[name].ops:
+            oc = op.opcode
+            if oc.startswith(COLLECTIVES):
+                n += not oc.endswith("-done")
+            elif oc == "while":
+                mb = re.search(r"body=%?([\w\.\-]+)", op.attrs)
+                mc = re.search(r"condition=%?([\w\.\-]+)", op.attrs)
+                trip = (_cond_trip_count(comps[mc.group(1)])
+                        if mc and mc.group(1) in comps else None)
+                n += (trip or 1) * (count(mb.group(1)) if mb else 0)
+            else:
+                n += sum(count(c) for c in re.findall(
+                    r"(?:calls|to_apply|called_computations)=\{?%?([\w\.\-]+)",
+                    op.attrs))
+        memo[name] = n
+        return n
+
+    entry = _entry(comps)
+    return count(entry) if entry is not None else 0

@@ -49,6 +49,7 @@ transfers overlapped with compute in the layer-ahead regime —
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -65,6 +66,7 @@ from repro.core import roofline as rl
 from repro.core import tiers as tr
 from repro.models import model as M
 from repro.models.frontends import synthetic_frontend_embeds
+from repro.profiler.hlo import collective_count
 from repro.runtime import capability
 from repro.runtime import serve as serve_rt
 from repro.serving.batcher import ContinuousBatcher
@@ -458,18 +460,26 @@ class ServingEngine:
             mode=ecfg.admission, knee_excess=ecfg.knee_excess,
         )
         if cells.paged:
-            self.caches = M.make_paged_decode_caches(
+            make_caches = functools.partial(
+                M.make_paged_decode_caches,
                 cfg, ecfg.n_slots, cells.max_seq_total, cells.page_tokens,
                 enc_len=self._enc_len(), pool_dtype=cells.pool_dtype,
                 sz_granularity=cells.sz_granularity,
             )
         else:
-            self.caches = M.make_decode_caches(
+            make_caches = functools.partial(
+                M.make_decode_caches,
                 cfg, ecfg.n_slots, cells.max_seq_total,
                 enc_len=self._enc_len(),
             )
         if cells.cache_shardings is not None:
-            self.caches = jax.device_put(self.caches, cells.cache_shardings)
+            # each chip makes only its shard: made whole on one chip, the
+            # pool (10.7 GB for mistral_nemo_12b's 16 x 8192 int8 slots)
+            # does not fit beside that chip's weights
+            self.caches = jax.jit(
+                make_caches, out_shardings=cells.cache_shardings)()
+        else:
+            self.caches = make_caches()
         # physical memory substrate: host-resident pool twin reconciled
         # against the pager's tier map once per decode step. Disabled
         # when requested off, on the contiguous layout, and on cache
@@ -517,6 +527,8 @@ class ServingEngine:
             self._draft_tok_bytes = total_b / (ecfg.n_slots * dseq)
             self._draft_params_n = cells.draft_cfg.active_param_count()
         self._active_params = cfg.active_param_count()
+        # cell -> cross-chip collective ops one call runs (`_call_cell`)
+        self.collectives: Dict[str, int] = {}
         self.steps = 0
         self.virtual_s = 0.0
         self._t_compute_s = 0.0
@@ -598,6 +610,18 @@ class ServingEngine:
 
     def compile_counts(self) -> Dict[str, int]:
         return self.cells.compile_counts()
+
+    def _call_cell(self, name: str, fn, *args):
+        """Call an engine cell. Its first call also reads, from the
+        compiled program, the cross-chip collective ops one call runs
+        (`self.collectives[name]`; 0 without a mesh); the call then runs
+        that same compiled program."""
+        if name not in self.collectives:
+            n = 0
+            if self.ctx.mesh is not None and hasattr(fn, "lower"):
+                n = collective_count(fn.lower(*args).compile().as_text())
+            self.collectives[name] = n
+        return fn(*args)
 
     # ------------------------------------------------------------ admit
     def _frontend_extras(self, req: Request, bucket: int) -> dict:
@@ -737,8 +761,9 @@ class ServingEngine:
         end = slot.prefill_pos + C
         final = end == req.prompt_len
         with span("engine.prefill_chunk", request_id=req.request_id,
-                  start=slot.prefill_pos, tokens=C, final=final):
+                  start=slot.prefill_pos, tokens=C, final=final) as sp:
             self._run_chunk(slot, end, final)
+            sp.set_metadata(collectives=self.collectives["chunk"])
         return True
 
     def _run_chunk(self, slot, end: int, final: bool) -> None:
@@ -750,7 +775,8 @@ class ServingEngine:
         toks = jnp.asarray(req.tokens[None, slot.prefill_pos:end])
         if req.wall.first_chunk_ns is None:
             req.wall.first_chunk_ns = now_ns()
-        tok, self.caches = self.cells.chunk_fn(
+        tok, self.caches = self._call_cell(
+            "chunk", self.cells.chunk_fn,
             self.params, toks, self.caches, np.int32(slot.index),
             np.int32(slot.prefill_pos // C),
             self._block_table_dev(),
@@ -1169,12 +1195,14 @@ class ServingEngine:
                     self.caches = self.cells.copy_fn(
                         self.caches, np.int32(old), np.int32(new)
                     )
-                next_tok, finite, self.caches = self.cells.decode_fn(
+                next_tok, finite, self.caches = self._call_cell(
+                    "decode", self.cells.decode_fn,
                     self.params, jnp.asarray(self.tokens), self.caches,
                     jnp.asarray(t_vec), self._block_table_dev(),
                 )
             else:
-                next_tok, finite, self.caches = self.cells.decode_fn(
+                next_tok, finite, self.caches = self._call_cell(
+                    "decode", self.cells.decode_fn,
                     self.params, jnp.asarray(self.tokens), self.caches,
                     jnp.asarray(t_vec),
                 )
@@ -1369,7 +1397,8 @@ class ServingEngine:
                 self.caches = self.cells.copy_fn(
                     self.caches, np.int32(old), np.int32(new)
                 )
-            greedy, finite, self.caches = self.cells.verify_fn(
+            greedy, finite, self.caches = self._call_cell(
+                "verify", self.cells.verify_fn,
                 self.params, jnp.asarray(cand), self.caches,
                 jnp.asarray(t_vec), self._block_table_dev(),
             )
@@ -1559,11 +1588,13 @@ class ServingEngine:
         stats = {"step": self.steps, "n_active": n_active}
         if self.cells.paged:
             stats["kv_pages"] = self._kv_pages()
-        with span("engine.decode", **stats):
+        with span("engine.decode", **stats) as sp:
             if self.cells.verify_fn is not None:
                 self._step_speculative()
+                sp.set_metadata(collectives=self.collectives["verify"])
             else:
                 self._step_decode()
+                sp.set_metadata(collectives=self.collectives["decode"])
         return "decode"
 
     def begin_capture(self) -> dict:

@@ -41,10 +41,15 @@ SPANS = {
                     "prefill and insert); stat request_id",
     "engine.prefill_chunk": "one prompt chunk: pager extend, chunk cell, "
                             "first-token read on the final chunk; stats "
-                            "request_id, start, tokens, final",
+                            "request_id, start, tokens, final, collectives "
+                            "(cross-chip collective ops the chunk cell "
+                            "runs a call, read once from its compiled "
+                            "program; 0 without a mesh)",
     "engine.decode": "one decode (or speculative verify) step over the "
                      "slot batch; stats step, n_active, kv_pages (paged "
-                     "cells: pages the decode kernel walks)",
+                     "cells: pages the decode kernel walks), collectives "
+                     "(as engine.prefill_chunk's, of the decode or verify "
+                     "cell)",
     "engine.decode.dispatch": "tail pages and COW copies, block-table "
                               "upload, the decode cell's call",
     "engine.decode.wait": "the host blocked on the device for the step's "

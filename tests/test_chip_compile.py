@@ -282,3 +282,52 @@ def test_engine_decode_cell_compiles_smollm_360m(one_chip):
     finally:
         kernels.force_backend(None)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cell", ["decode", "chunk"])
+def test_engine_cells_fit_mistral_nemo_12b_tp4(topo, one_chip, cell):
+    """The benchmark's tp cell: mistral_nemo_12b at its published widths
+    over a (data=1, model=4) mesh of the described v5e:2x2, 16 slots x
+    8192 tokens of int8 pool in pages of 16, chunks of 512. Each cell's
+    arguments plus temporaries a chip fit the chip's 16 GB of HBM."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro import configs, kernels
+    from repro.launch.mesh import ctx_for_mesh
+    from repro.runtime import serve as serve_rt
+
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
+                ("data", "model"))
+    slots = 16
+    kernels.force_backend("pallas")
+    try:
+        cells = serve_rt.make_engine_cells(
+            configs.get("mistral_nemo_12b"),
+            ctx_for_mesh(mesh, fsdp=False, remat="none"), mesh=mesh,
+            n_slots=slots, max_seq=8192, buckets=(512,), paged=True,
+            page_tokens=PAGE, prefill_chunk=512, pool_dtype="int8")
+
+        def shaped(tree, shardings):
+            return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=s), tree, shardings)
+
+        rep = NamedSharding(mesh, P())
+        params = shaped(cells.abstract_params, cells.param_shardings)
+        caches = shaped(cells.abstract_caches, cells.cache_shardings)
+        bt = jax.ShapeDtypeStruct((slots, cells.n_pages), jnp.int32,
+                                  sharding=rep)
+        if cell == "decode":
+            vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=rep)
+            compiled = cells.decode_fn.lower(params, vec, caches, vec,
+                                             bt).compile()
+        else:
+            idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+            toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=rep)
+            compiled = cells.chunk_fn.lower(params, toks, caches, idx, idx,
+                                            bt).compile()
+    finally:
+        kernels.force_backend(None)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

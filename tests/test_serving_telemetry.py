@@ -7,6 +7,10 @@ the tokens alone."""
 
 import dataclasses
 import glob
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -197,3 +201,82 @@ def test_kv_pages_skips_parked_rows():
     assert eng._kv_pages() == 1 + 1 + 3
     slots[1].phase = "prefill"                      # parked again
     assert eng._kv_pages() == 1 + 3
+
+
+def test_collectives_stat_is_zero_without_a_mesh(traced):
+    _, spans = traced
+    cells = _named(spans, "engine.decode") + _named(spans,
+                                                    "engine.prefill_chunk")
+    assert cells
+    assert {s[3]["collectives"] for s in cells} == {0}
+
+
+MESH_RUN = """
+import glob, json, re, sys, tempfile
+import jax, jax.numpy as jnp, numpy as np
+from repro import configs, kernels
+from repro.launch.mesh import ctx_for_mesh
+from repro.serving import EngineConfig, Request, ServingEngine
+
+cfg = configs.reduced("mistral_nemo_12b")
+mesh = jax.make_mesh((1, 2), ("data", "model"), devices=jax.devices()[:2],
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+kernels.force_backend("interpret")
+eng = ServingEngine.build(
+    cfg, ctx_for_mesh(mesh, fsdp=False, remat="none"),
+    EngineConfig(n_slots=2, max_seq=64, page_tokens=16, prefill_chunk=16,
+                 prefill_buckets=(16,), pool_dtype="int8"), mesh=mesh)
+d = tempfile.mkdtemp()
+jax.profiler.start_trace(d)
+eng.run([Request(request_id=i, tokens=np.arange(32, dtype=np.int32) + i,
+                 max_new_tokens=3, arrival=0.0) for i in range(2)])
+jax.profiler.stop_trace()
+path, = glob.glob(d + "/**/*.xplane.pb", recursive=True)
+stats = {}
+for plane in jax.profiler.ProfileData.from_file(path).planes:
+    for line in plane.lines:
+        for ev in line.events:
+            if ev.name in ("engine.decode", "engine.prefill_chunk"):
+                stats.setdefault(ev.name, set()).add(
+                    dict(ev.stats)["collectives"])
+
+def text_count(fn, *args):
+    # a collective in the layer scan's body runs once a layer
+    n = 0
+    for line in fn.lower(*args).compile().as_text().splitlines():
+        op = re.search(r"= \\S+ ((all-reduce|all-gather|reduce-scatter"
+                       r"|all-to-all|collective-permute)[\\w-]*)\\(", line)
+        if op and not op.group(1).endswith("-done"):
+            body = "/while/body/" in line
+            n += cfg.num_layers if body else 1
+    return n
+
+bt = eng._block_table_dev()
+vec = jnp.zeros(2, jnp.int32)
+print("RESULT", json.dumps({
+    "spans": {k: sorted(v) for k, v in stats.items()},
+    "decode": text_count(eng.cells.decode_fn, eng.params, vec, eng.caches,
+                         vec, bt),
+    "chunk": text_count(eng.cells.chunk_fn, eng.params,
+                        jnp.zeros((1, 16), jnp.int32), eng.caches,
+                        np.int32(0), np.int32(0), bt)}))
+"""
+
+
+def test_collectives_stat_counts_the_compiled_cells_collectives():
+    """On a (1, 2) mesh of forced CPU devices the stat is the count of
+    collective ops in the compiled cell's text, each op in the layer
+    scan's body once a layer: above 0, the same on every span."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    env.pop("ALLOW_MULTIPLE_LIBTPU_LOAD", None)
+    r = subprocess.run([sys.executable, "-c", MESH_RUN], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = [x for x in r.stdout.splitlines() if x.startswith("RESULT ")][-1]
+    got = json.loads(line[len("RESULT "):])
+    assert got["decode"] > 0 and got["chunk"] > 0
+    assert got["spans"] == {"engine.decode": [got["decode"]],
+                            "engine.prefill_chunk": [got["chunk"]]}
